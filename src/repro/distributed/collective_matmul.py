@@ -20,16 +20,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` with a fallback for JAX versions without it
-    (``psum(1, axis)`` is the classic idiom — it constant-folds to the
-    static axis size inside the mapped region)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ring_reduce_matmul(x_loc: jax.Array, w_loc: jax.Array, axis_name: str,
                        *, chunks: int = 4) -> jax.Array:
     """x_loc [B, k_loc] @ w_loc [k_loc, n] summed over the mesh axis.
@@ -38,7 +28,7 @@ def ring_reduce_matmul(x_loc: jax.Array, w_loc: jax.Array, axis_name: str,
     each finished chunk starts circulating the ring while the next chunk is
     still on the MXU.
     """
-    n_ranks = _axis_size(axis_name)
+    n_ranks = jax.lax.axis_size(axis_name)
     n = w_loc.shape[-1]
     chunks = min(chunks, n)
     assert n % chunks == 0
@@ -66,7 +56,7 @@ def allgather_matmul(x_loc: jax.Array, w_loc: jax.Array,
     Y partial rows [b_loc*n_ranks, n_loc] assembled ring-rotated]:
     returns [B, n_loc] with B = b_loc × n_ranks in ring order.
     """
-    n_ranks = _axis_size(axis_name)
+    n_ranks = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n_ranks) for i in range(n_ranks)]
     b_loc = x_loc.shape[0]

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.partitioning import shard_map
 from repro.models.moe import _dispatch_group  # reference router/dispatch
 
 
@@ -91,7 +90,7 @@ def ep_moe_apply(cfg, p, x, mesh, *, axis: str = "model",
         "w_up": P(axis, None, None),
         "w_down": P(axis, None, None),
     }
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P(token_axes, None, None)),
         out_specs=P(token_axes, None, None), check_vma=False)

@@ -89,22 +89,6 @@ def shard(x: jax.Array, *axes) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes it at top level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` whose equivalent knob is
-    ``check_rep``.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def is_spec_leaf(t) -> bool:
     """Logical-axis tuples are leaves; NamedTuples (pytree nodes) are not."""
     return (isinstance(t, tuple) and not hasattr(t, "_fields")) or t is None

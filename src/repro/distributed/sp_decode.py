@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import resolve_decode_flags
 from repro.core.qlinear import is_packed  # noqa: F401 (doc cross-ref)
-from repro.distributed.partitioning import current_mesh, dp_axes, shard_map
+from repro.distributed.partitioning import current_mesh, dp_axes
 from repro.kernels import ops
 from repro.serving.lop_select import k_keep_blocks
 
@@ -147,12 +147,12 @@ def sp_decode_attention(cfg, qi, qsc, ki, vi, ksc, vsc, feat, cl, lengths, *,
             return body(qi, qsc, None, None, None, None, None, cl, lengths,
                         act)
 
-        fn = shard_map(body_nw, mesh=mesh,
+        fn = jax.shard_map(body_nw, mesh=mesh,
                            in_specs=(new_tok_spec2, new_tok_spec2,
                                      cache_spec, rep1, rep1),
                            out_specs=out_specs, check_vma=False)
         return fn(qi, qsc, cl, lengths, active)
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(qi, qsc, ki, vi, ksc, vsc, feat, cl, lengths, active)

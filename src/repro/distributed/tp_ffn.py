@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.partitioning import current_mesh, shard_map
+from repro.distributed.partitioning import current_mesh
 from repro.kernels import ops
 
 _state = threading.local()
@@ -105,7 +105,7 @@ def ffn_fused_tp(x, gu_packed, gu_scale, down_packed, down_scale, *,
                              act=act)
         return jax.lax.psum(part, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, spec(gu4.ndim, gu4.ndim - 1),
                   spec(gs4.ndim, gs4.ndim - 1),

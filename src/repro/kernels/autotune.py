@@ -19,7 +19,7 @@ setting is bitwise the default, pinned by the kernel test matrix):
                     eg (experts per grid step)
   ffn               bm, bf, bn, bkq
   prefill           block (kv tile), bq (query-row tile)
-  decode            n_slots (candidate DMA slots)
+  decode            n_slots (candidate K/V buffers, 1 or 2)
   ================  ==========================================
 
 Precedence at dispatch (``lookup``):
@@ -164,23 +164,32 @@ def valid_params(kernel: str, dims: dict, params: dict) -> bool:
     if kernel == "qlinear":
         bkq = p.get("bkq", 0)
         return (p.get("bm", 8) % 8 == 0 and p.get("bm", 8) >= 8
-                and d["n"] % p.get("bn", d["n"]) == 0
-                and (bkq == 0 or d["k"] % bkq == 0)
+                and _lane_block(d["n"], p.get("bn", d["n"]))
+                and (bkq == 0 or _lane_block(d["k"], bkq))
                 and d["e"] % p.get("eg", 1) == 0)
     if kernel == "ffn":
         bkq = p.get("bkq", 0)
         return (p.get("bm", 8) % 8 == 0 and p.get("bm", 8) >= 8
-                and d["f"] % p.get("bf", d["f"]) == 0
-                and d["n"] % p.get("bn", d["n"]) == 0
-                and (bkq == 0 or d["k"] % bkq == 0))
+                and _lane_block(d["f"], p.get("bf", d["f"]))
+                and _lane_block(d["n"], p.get("bn", d["n"]))
+                and (bkq == 0 or _lane_block(d["k"], bkq)))
     if kernel == "prefill":
         block = p.get("block", 0)
         bq = p.get("bq", 0)
-        # the wrapper pads M up to `block`, so any block ≥ 1 is legal
-        return block >= 1 and (bq == 0 or d["r"] % bq == 0)
+        # the wrapper pads M up to `block`, so any block ≥ 1 is legal; a
+        # query-row tile is a sublane block: a multiple of 8 or all R rows
+        return block >= 1 and (bq == 0 or (
+            d["r"] % bq == 0 and (bq % 8 == 0 or bq == d["r"])))
     if kernel == "decode":
-        return p.get("n_slots", 2) >= 1
+        # Mosaic pipelines one or two buffers per input, no deeper
+        return 1 <= p.get("n_slots", 2) <= 2
     return False
+
+
+def _lane_block(n: int, b: int) -> bool:
+    """A column block Mosaic takes: divides n and is a multiple of 128
+    lanes or the whole dim."""
+    return b >= 1 and n % b == 0 and (b % 128 == 0 or b == n)
 
 
 def lookup(kernel: str, dims: dict) -> dict:
@@ -472,7 +481,8 @@ def candidates(kernel: str, dims: dict, *,
                         raw.append({"bm": bm, "bn": bn, "bkq": bkq,
                                     "eg": eg})
         default = {"bm": min(128, _round_up(max(d["m"], 1), 8)),
-                   "bn": _fallback_block(d["n"]), "bkq": 0, "eg": 1}
+                   "bn": _fallback_block(d["n"], d["k"] // 4), "bkq": 0,
+                   "eg": 1}
     elif kernel == "ffn":
         for bm in _pow2_range(8, min(256, _round_up(max(d["m"], 1), 8))):
             for bf in _divisors(d["f"], 32, 512):
@@ -481,15 +491,15 @@ def candidates(kernel: str, dims: dict, *,
                         raw.append({"bm": bm, "bf": bf, "bn": bn,
                                     "bkq": bkq})
         default = {"bm": min(128, _round_up(max(d["m"], 1), 8)),
-                   "bf": _fallback_block(d["f"]),
-                   "bn": _fallback_block(d["n"]), "bkq": 0}
+                   "bf": _fallback_block(d["f"], None),     # gated
+                   "bn": _fallback_block(d["n"], d["f"] // 4), "bkq": 0}
     elif kernel == "prefill":
         for block in _pow2_range(32, 512):
             for bq in [0] + _divisors(d["r"], 8, d["r"]):
                 raw.append({"block": block, "bq": bq})
         default = {"block": min(128, d["m"]), "bq": 0}
     elif kernel == "decode":
-        raw = [{"n_slots": ns} for ns in (1, 2, 3, 4, 6, 8)]
+        raw = [{"n_slots": ns} for ns in (1, 2)]
         default = {"n_slots": 2}
     else:
         raise ValueError(kernel)
@@ -508,14 +518,33 @@ def candidates(kernel: str, dims: dict, *,
     return out
 
 
-def _fallback_block(n: int, target: int = 128) -> int:
-    """ops._pick_block, restated here to describe the default candidate."""
+def pick_block(n: int, col_bytes: int | None, target: int = 128) -> int:
+    """Default column block for a weight of n columns, ``col_bytes``
+    VMEM bytes each: n itself when it fits one ``target`` block (one MXU
+    lane width), else ``target`` where it divides n. Mosaic takes a lane
+    block only as a multiple of 128 or the whole dim, so any other n runs
+    as one whole-dim block if its double-buffered weight block fits the
+    VMEM budget. Where it does not, or where the n columns are not the
+    whole array (``col_bytes`` None), no legal block exists and this
+    raises (``quantize_params`` pads the FFN hidden width so it never
+    does)."""
     if n <= target:
         return n
-    for b in range(target, 0, -1):
-        if n % b == 0:
-            return b
-    return 1
+    if n % target == 0:
+        return target
+    if col_bytes is not None and 2 * n * col_bytes <= vmem_budget():
+        return n
+    raise ValueError(f"no legal column block for n={n}: pad it to a "
+                     f"multiple of {target}")
+
+
+def _fallback_block(n: int, col_bytes: int | None) -> int | None:
+    """pick_block, or None where it refuses n (the default candidate then
+    fails the screen)."""
+    try:
+        return pick_block(n, col_bytes)
+    except ValueError:
+        return None
 
 
 # ---------------------------------------------------------------------------

@@ -1,33 +1,39 @@
-"""Fused batched decode attention — one Pallas kernel for the whole step.
+"""Fused batched decode attention — the whole step in two Pallas launches.
 
-This is the decode hot path of the paper (§III) as ONE ``pallas_call``
-whose grid spans (batch × kv-head) with a sequential streaming axis,
+This is the decode hot path of the paper (§III): each ``pallas_call``
+has a grid spanning (batch × kv-head) with a sequential streaming axis,
 replacing the previous per-head small-kernel dispatch (``lop_screen`` +
 jnp block top-K + ``sparse_decode`` under a triple ``vmap``) that TeLLMe
 v2 / HSA-style analyses identify as the utilization killer on edge
-accelerators (PAPERS.md). Per (b, kv-head) lane the streaming axis runs
-three fused phases back to back (DESIGN.md §Fused-decode-kernel):
+accelerators (PAPERS.md). Per (b, kv-head) lane the LOP path runs three
+phases (DESIGN.md §Fused-decode-kernel):
 
-  screen   steps ``j < NB`` stream the packed 4-bit (sgn‖LO) feature
-           cache block by block: nibbles expand to pot-int8 in VMEM, one
-           int8 MXU dot yields surrogate scores, invalid tokens mask to
-           INT32_MIN, and the per-block maxima land in VMEM scratch
-           (fully-masked blocks score −inf so they can never be picked).
-  select   at step ``j == NB`` the comparison-free bucketized top-K
-           (the ASIC's histogram + prefix-scan selector, mirroring
-           :func:`repro.core.lop.comparison_free_topk` op for op) turns
-           the block scores into an emission *rank* per block — no
-           comparator tree, no sort.
-  exact    steps ``j ≥ NB`` walk the K selected candidates in rank
-           order. Each step resolves its block id from the rank scratch
-           and DMAs ONLY that int8 K/V block (plus scales) from HBM into
-           a double-buffered VMEM slot — candidate c+1's fetch starts
-           *before* candidate c's wait-and-compute, so the HBM latency
-           hides behind MXU work (the paper's head-level pipelining) —
-           then folds it into f32 online-softmax state (m/ℓ/acc scratch,
+  screen   launch 1, steps ``j < NB``: the packed 4-bit (sgn‖LO)
+           feature cache streams block by block; nibbles expand to
+           pot-int8 in VMEM, one int8 MXU dot yields surrogate scores,
+           invalid tokens mask to INT32_MIN, and the per-block maxima
+           land in VMEM scratch (fully-masked blocks score −inf so they
+           can never be picked).
+  select   launch 1, step ``j == NB − 1``: the comparison-free
+           bucketized top-K (the ASIC's histogram + prefix-scan
+           selector, mirroring :func:`repro.core.lop.comparison_free_topk`
+           op for op) turns the block scores into an emission *rank* per
+           block — no comparator tree, no sort. A few jnp ops turn the
+           ranks into one block id per candidate.
+  exact    launch 2, step ``c`` per candidate in rank order: the block
+           ids ride in SMEM as scalar prefetch, and the BlockSpec index
+           map fetches ONLY candidate c's int8 K/V block (plus scales)
+           from HBM. The pipeline fetches candidate c+1 while c is on
+           the MXU (the paper's head-level pipelining), then c folds
+           into f32 online-softmax state (m/ℓ/acc scratch,
            output-stationary like the paper's OS dataflow). Un-selected
-           blocks are never fetched — the LOP traffic win survives
-           fusion.
+           blocks are never fetched — the LOP traffic win survives.
+
+The exact phase fetches through the pipeline, not a hand-written DMA:
+Mosaic refuses a DMA of a [block, d] slice whose lane dim d is not a
+multiple of 128 (bitnet-3b has d = 100), while a BlockSpec block may
+span the whole dim. A gated-off candidate repeats the previous block id,
+so the pipeline fetches nothing for it.
 
 The final step normalizes with an ``ℓ > 0`` guard, so a lane with
 ``new_len == 0`` (a retired slot-pool lane) emits exactly zero.
@@ -38,26 +44,27 @@ Scalar-prefetch contract
 ``pos_offset`` int32 [1] (global token position of this cache shard —
 the SP quota-sharded path passes ``rank · M_local``) ride in SMEM ahead
 of the grid. They drive validity masking, the in-block live interval
-[start, end) of each candidate, and nothing else — all tensor operands
-are addressed by the grid alone, which is what lets one compiled kernel
-serve every lane population and every SP shard.
+[start, end) of each candidate, and nothing else, which is what lets one
+compiled kernel serve every lane population and every SP shard. The
+exact launch also prefetches the candidates' block ids and gates
+(int32 [B·Hkv·n_cand] each), which address its K/V blocks.
 
 Modes (all static):
 
-  * ``use_lop=False``  — dense baseline: the same grid streams every
-    K/V block through the online-softmax phase (no screen, no DMA).
+  * ``use_lop=False``  — dense baseline: one launch streams every K/V
+    block through the online-softmax phase (no screen, no gather).
   * ``shared_select``  — one candidate set per kv head (group max of
-    the surrogate scores) instead of per q-head: K DMA gathers instead
-    of G·K.
+    the surrogate scores) instead of per q-head: K block gathers
+    instead of G·K.
   * ``return_stats``   — also emit the raw (m, ℓ) softmax stats so the
     SP path can merge shards flash-decoding style without recomputing.
 
-Validated in interpret mode (the container's mandated mode). The
-selection phase is tiled for real-TPU compilation: every op in
-:func:`repro.core.lop.comparison_free_rank` keeps 2-D (sublane, lane)
-shape — the histogram runs as per-bucket lane-reductions over [R, M]
-broadcast-compares and the index-order prefix sums as f32 MXU dots
-against a triangular ones matrix — with ranks bitwise the flat-op
+Interpret mode is the CPU test path; ``tests/test_chip_compile.py``
+compiles both forms with Mosaic for a v5e at bitnet-3b widths. Every op
+in :func:`repro.core.lop.comparison_free_rank` keeps 2-D (sublane,
+lane) shape — the histogram runs as per-bucket lane-reductions over
+[R, M] broadcast-compares and the index-order prefix sums as f32 MXU
+dots against a triangular ones matrix — with ranks bitwise the flat-op
 implementation it replaced.
 """
 
@@ -93,8 +100,10 @@ def _online_update(s, v_deq, rows, m_ref, l_ref, acc_ref):
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new[:, :1])
     l_ref[rows, :] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+    # HIGHEST: Mosaic's default f32 dot rounds its operands to bf16
     acc_ref[rows, :] = acc_ref[rows, :] * alpha[:, :1] + jnp.dot(
-        p, v_deq, preferred_element_type=jnp.float32)
+        p, v_deq, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     m_ref[rows, :] = m_new
 
 
@@ -107,158 +116,135 @@ def _flush(o_ref, m_out, l_out, m_ref, l_ref, acc_ref, return_stats):
 
 
 # ---------------------------------------------------------------------------
-# Fused LOP kernel body
+# LOP launch 1: screen + select
 # ---------------------------------------------------------------------------
 
-def _fused_lop_kernel(nl_ref, po_ref, qi_ref, qs_ref, feat_ref,
-                      k_hbm, v_hbm, ks_hbm, vs_hbm,
-                      o_ref, *rest, nb, g, hkv, block, k_keep, window,
-                      softmax_scale, n_buckets, n_slots, shared_select,
-                      return_stats):
-    """Grid (b·hkv, NB + n_cand): screen → select → DMA'd exact attention."""
-    if return_stats:
-        m_out, l_out = rest[0], rest[1]
-        rest = rest[2:]
-    else:
-        m_out = l_out = None
-    (blk_ref, rank_ref, m_ref, l_ref, acc_ref,
-     kb_ref, vb_ref, ksb_ref, vsb_ref, sem) = rest
-
+def _lop_screen_kernel(nl_ref, po_ref, qi_ref, feat_ref, rank_ref, blk_ref,
+                       *, nb, hkv, block, k_keep, window, n_buckets,
+                       shared_select):
+    """Grid (b·hkv, NB): screen every feature block, then rank them."""
     bh = pl.program_id(0)
     j = pl.program_id(1)
     nl = nl_ref[bh // hkv]
     po = po_ref[0]
-    n_cand = k_keep if shared_select else g * k_keep
 
     @pl.when(j == 0)
     def _init():
         blk_ref[...] = jnp.full_like(blk_ref, -jnp.inf)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # ---- screen: surrogate block scores from the packed feature cache ----
-    @pl.when(j < nb)
-    def _screen():
-        qp = pot(qi_ref[0])                              # [G, d] int8
-        kp = _nibbles_to_pot(feat_ref[0], qp.shape[-1])  # [block, d] int8
-        s = jax.lax.dot_general(
-            qp, kp, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)            # [G, block]
-        tpos = po + j * block + jax.lax.broadcasted_iota(jnp.int32,
-                                                         s.shape, 1)
-        tvalid = tpos < nl
-        if window:
-            tvalid &= tpos >= nl - window
-        s = jnp.where(tvalid, s, INT32_MIN)
-        if shared_select:
-            s = jnp.max(s, axis=0, keepdims=True)        # [1, block]
-        score = jnp.max(s, -1, keepdims=True).astype(jnp.float32)
-        # a block with no valid token must never be selectable
-        any_valid = jnp.any(tvalid[:1])
-        blk_ref[:, pl.ds(j, 1)] = jnp.where(any_valid, score, -jnp.inf)
+    qp = pot(qi_ref[0])                                  # [G, d] int8
+    kp = _nibbles_to_pot(feat_ref[0], qp.shape[-1])      # [block, d] int8
+    s = jax.lax.dot_general(
+        qp, kp, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)                # [G, block]
+    tpos = po + j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    tvalid = tpos < nl
+    if window:
+        tvalid &= tpos >= nl - window
+    s = jnp.where(tvalid, s, INT32_MIN)
+    if shared_select:
+        s = jnp.max(s, axis=0, keepdims=True)            # [1, block]
+    score = jnp.max(s, -1, keepdims=True).astype(jnp.float32)
+    # a block with no valid token must never be selectable
+    any_valid = jnp.any(tvalid[:1])
+    # lane j of the score row, written as a masked whole-row update:
+    # Mosaic only stores lane slices at multiples of 128
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk_ref.shape, 1)
+    blk_ref[...] = jnp.where(lane == j, jnp.where(any_valid, score, -jnp.inf),
+                             blk_ref[...])
 
     # ---- select: comparison-free top-K → emission ranks (once). The rank
     # computation is THE shared implementation from core.lop (also behind
     # the jnp oracle's comparison_free_topk), running here inside the
     # kernel body — kernel and oracle cannot drift apart. ----
-    @pl.when(j == nb)
+    @pl.when(j == nb - 1)
     def _select():
-        rank_ref[...] = comparison_free_rank(blk_ref[...], k_keep,
-                                             n_buckets)
+        rank_ref[0] = comparison_free_rank(blk_ref[...], k_keep, n_buckets)
 
-    # ---- exact: slot-buffered candidate DMA + online softmax ----
-    # Candidate c's K/V/scale blocks are fetched into slot c % n_slots;
-    # the copy for c + n_slots − 1 starts BEFORE the wait-and-compute of
-    # c, so up to n_slots − 1 fetches are in flight behind the MXU work
-    # of the current candidate — the head-level pipelining the paper
-    # overlaps in silicon. n_slots = 2 is classic double buffering (the
-    # historical shape); the slot count only changes WHEN a block is
-    # fetched, never which blocks fold or in what order, so every
-    # n_slots ≥ 1 is bitwise n_slots = 2 (DESIGN.md §Autotuning).
-    def _resolve(c):
-        """Candidate number → (gated?, selected block id)."""
+
+def _candidates(rank, k_keep: int, nb: int):
+    """Emission ranks [BH, R, NBp] → (block id, gate) int32, flat
+    [BH·R·k_keep] in candidate order c = row·k_keep + rank.
+
+    A gated-off candidate (fewer live blocks than k_keep) points at the
+    block of the candidate before it, so the pipeline sees an unchanged
+    block index and fetches nothing for it.
+    """
+    kc = jnp.arange(k_keep, dtype=jnp.int32)[:, None]
+    hit = rank[:, :, None, :] == kc                      # [BH, R, K, NBp]
+    cols = jnp.arange(rank.shape[-1], dtype=jnp.int32)
+    gate = jnp.any(hit, -1).reshape(-1)
+    ids = jnp.min(jnp.where(hit, cols, nb), -1).reshape(-1)
+    pos = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(gate, pos, -1), axis=0)
+    ids = jnp.where(last >= 0, ids[jnp.maximum(last, 0)], 0)
+    return ids.astype(jnp.int32), gate.astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# LOP launch 2: exact attention over the selected blocks
+# ---------------------------------------------------------------------------
+
+def _lop_exact_kernel(nl_ref, po_ref, id_ref, gate_ref, qi_ref, qs_ref,
+                      k_ref, v_ref, ks_ref, vs_ref, o_ref, *rest, g, n_cand,
+                      hkv, block, k_keep, window, softmax_scale,
+                      shared_select, return_stats):
+    """Grid (b·hkv, n_cand): fold candidate c's K/V block (fetched by the
+    pipeline from the scalar-prefetched block id) into the softmax state."""
+    if return_stats:
+        m_out, l_out = rest[0], rest[1]
+        rest = rest[2:]
+    else:
+        m_out = l_out = None
+    m_ref, l_ref, acc_ref = rest
+
+    bh = pl.program_id(0)
+    c = pl.program_id(1)
+    nl = nl_ref[bh // hkv]
+    po = po_ref[0]
+    cand = bh * n_cand + c
+
+    @pl.when(c == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(gate_ref[cand] != 0)
+    def _attend():
+        idx = id_ref[cand]
+        kb = k_ref[0]                                    # [block, d]
+        ksb = ks_ref[0]                                  # [block, 1]
         if shared_select:
-            rank_row = rank_ref[0:1, :]
-            kc = c
+            rows = slice(None)
+            q = qi_ref[0]                                # [G, d]
+            qs = qs_ref[0]                               # [G, 1]
         else:
-            rank_row = rank_ref[pl.ds(c // k_keep, 1), :]
-            kc = c % k_keep
-        cols = jax.lax.broadcasted_iota(jnp.int32, rank_row.shape, 1)
-        hit = rank_row == kc
-        return jnp.any(hit), jnp.min(jnp.where(hit, cols, nb))
+            # query row c // k_keep, picked by a masked sum over the G
+            # rows: Mosaic cannot load one int8 row at a dynamic sublane
+            # offset
+            r = c // k_keep
+            rows = pl.ds(r, 1)
+            pick = jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0) == r
+            q = jnp.sum(jnp.where(pick, qi_ref[0].astype(jnp.int32), 0), 0,
+                        keepdims=True).astype(jnp.int8)  # [1, d]
+            qs = jnp.sum(jnp.where(pick, qs_ref[0], 0.0), 0, keepdims=True)
+        s = jax.lax.dot_general(
+            q, kb, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+        s = s * qs * ksb.reshape(1, block) * softmax_scale
+        # in-block live interval [start, end): suffix cut by the cache
+        # length, prefix cut by the SWA window
+        blk_start = po + idx * block
+        end = jnp.clip(nl - blk_start, 0, block)
+        tstart = jnp.clip(nl - window - blk_start, 0, block) if window else 0
+        t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((t >= tstart) & (t < end), s, NEG_INF)
+        v_deq = v_ref[0].astype(jnp.float32) * vs_ref[0]
+        _online_update(s, v_deq, rows, m_ref, l_ref, acc_ref)
 
-    def _copies(slot, idx):
-        start = idx * block
-        return [
-            pltpu.make_async_copy(k_hbm.at[bh, pl.ds(start, block), :],
-                                  kb_ref.at[slot], sem.at[slot, 0]),
-            pltpu.make_async_copy(v_hbm.at[bh, pl.ds(start, block), :],
-                                  vb_ref.at[slot], sem.at[slot, 1]),
-            pltpu.make_async_copy(ks_hbm.at[bh, pl.ds(start, block), :],
-                                  ksb_ref.at[slot], sem.at[slot, 2]),
-            pltpu.make_async_copy(vs_hbm.at[bh, pl.ds(start, block), :],
-                                  vsb_ref.at[slot], sem.at[slot, 3]),
-        ]
-
-    @pl.when(j >= nb)
-    def _cand():
-        c = j - nb
-        slot = jax.lax.rem(c, n_slots)
-        gate, idx = _resolve(c)
-
-        # warmup: the first candidate step fills slots 0..n_slots−2
-        for cc in range(min(n_slots - 1, n_cand)):
-            gate_w, idx_w = _resolve(cc)
-
-            @pl.when((c == 0) & gate_w)
-            def _warmup(cc=cc, gate_w=gate_w, idx_w=idx_w):
-                for cp in _copies(cc % n_slots, idx_w):
-                    cp.start()
-
-        if n_cand >= n_slots:
-            @pl.when(c + n_slots - 1 < n_cand)
-            def _prefetch_next():
-                gate_n, idx_n = _resolve(c + n_slots - 1)
-
-                @pl.when(gate_n)
-                def _():
-                    for cp in _copies(jax.lax.rem(c + n_slots - 1, n_slots),
-                                      idx_n):
-                        cp.start()
-
-        @pl.when(gate)
-        def _attend():
-            for cp in _copies(slot, idx):
-                cp.wait()
-            kb = kb_ref[pl.ds(slot, 1)][0]               # [block, d]
-            ksb = ksb_ref[pl.ds(slot, 1)][0]             # [block, 1]
-
-            if shared_select:
-                rows = slice(None)
-                q = qi_ref[0]                            # [G, d]
-                qs = qs_ref[0]                           # [G, 1]
-            else:
-                rows = pl.ds(c // k_keep, 1)
-                q = qi_ref[0, rows, :]                   # [1, d]
-                qs = qs_ref[0, rows, :]
-            s = jax.lax.dot_general(
-                q, kb, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32).astype(jnp.float32)
-            s = s * qs * ksb.reshape(1, block) * softmax_scale
-            # in-block live interval [start, end): suffix cut by the cache
-            # length, prefix cut by the SWA window
-            blk_start = po + idx * block
-            end = jnp.clip(nl - blk_start, 0, block)
-            tstart = jnp.clip(nl - window - blk_start, 0, block) if window \
-                else 0
-            t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where((t >= tstart) & (t < end), s, NEG_INF)
-            v_deq = (vb_ref[pl.ds(slot, 1)][0].astype(jnp.float32)
-                     * vsb_ref[pl.ds(slot, 1)][0])
-            _online_update(s, v_deq, rows, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == nb + n_cand - 1)
+    @pl.when(c == n_cand - 1)
     def _finish():
         _flush(o_ref, m_out, l_out, m_ref, l_ref, acc_ref, return_stats)
 
@@ -335,47 +321,47 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
     feat      uint8  [BH, M, d/2]  packed (sgn‖LO) feature cache
     new_len   int32  [B]           valid tokens per lane (0 = retired slot)
     pos_off   int32  [1]           global position of cache row 0 (SP shard)
-    n_slots   candidate DMA slots in VMEM (≥ 1; 2 = double buffering, the
-              default; more slots deepen the fetch pipeline, bitwise)
+    n_slots   candidate K/V buffers in VMEM: 2 = double buffering, the
+              default; 1 fetches each block after the previous fold.
+              Mosaic pipelines no deeper. Bitwise either way.
     → f32 [BH, G, d]; with ``return_stats`` also (m, ℓ) f32 [BH, G, 1].
     """
     bhg, g, d = qi.shape
     m = k_cache.shape[1]
     assert m % block == 0, (m, block)
-    assert n_slots >= 1, n_slots
+    assert n_slots in (1, 2), n_slots
     nb = m // block
     nbp = _round_up(nb, 128)                 # lane-padded score scratch
     g_sel = 1 if shared_select else g
 
+    # index maps take (grid indices, *scalar-prefetch refs)
+    lane = lambda bh, j, *_: (bh, 0, 0)
     outs = [jax.ShapeDtypeStruct((bhg, g, d), jnp.float32)]
-    out_specs = [pl.BlockSpec((1, g, d), lambda bh, j, nl, po: (bh, 0, 0))]
+    out_specs = [pl.BlockSpec((1, g, d), lane)]
     if return_stats:
         outs += [jax.ShapeDtypeStruct((bhg, g, 1), jnp.float32)] * 2
-        out_specs += [pl.BlockSpec((1, g, 1),
-                                   lambda bh, j, nl, po: (bh, 0, 0))] * 2
+        out_specs += [pl.BlockSpec((1, g, 1), lane)] * 2
+    softmax_scratch = [
+        pltpu.VMEM((g, 128), jnp.float32),       # running max
+        pltpu.VMEM((g, 128), jnp.float32),       # running sum-exp
+        pltpu.VMEM((g, d), jnp.float32),         # output accumulator
+    ]
 
     if not use_lop:
+        block_j = lambda bh, j, *_: (bh, j, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bhg, nb),
             in_specs=[
-                pl.BlockSpec((1, g, d), lambda bh, j, nl, po: (bh, 0, 0)),
-                pl.BlockSpec((1, g, 1), lambda bh, j, nl, po: (bh, 0, 0)),
-                pl.BlockSpec((1, block, d),
-                             lambda bh, j, nl, po: (bh, j, 0)),
-                pl.BlockSpec((1, block, d),
-                             lambda bh, j, nl, po: (bh, j, 0)),
-                pl.BlockSpec((1, block, 1),
-                             lambda bh, j, nl, po: (bh, j, 0)),
-                pl.BlockSpec((1, block, 1),
-                             lambda bh, j, nl, po: (bh, j, 0)),
+                pl.BlockSpec((1, g, d), lane),
+                pl.BlockSpec((1, g, 1), lane),
+                pl.BlockSpec((1, block, d), block_j),
+                pl.BlockSpec((1, block, d), block_j),
+                pl.BlockSpec((1, block, 1), block_j),
+                pl.BlockSpec((1, block, 1), block_j),
             ],
             out_specs=out_specs if return_stats else out_specs[0],
-            scratch_shapes=[
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
-            ],
+            scratch_shapes=softmax_scratch,
         )
         out = pl.pallas_call(
             functools.partial(_fused_dense_kernel, nb=nb, hkv=hkv,
@@ -388,44 +374,55 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
         )(new_len, pos_off, qi, qsc, k_cache, v_cache, k_scale, v_scale)
         return out
 
-    n_cand = k_keep * (1 if shared_select else g)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bhg, nb + n_cand),
-        in_specs=[
-            pl.BlockSpec((1, g, d), lambda bh, j, nl, po: (bh, 0, 0)),
-            pl.BlockSpec((1, g, 1), lambda bh, j, nl, po: (bh, 0, 0)),
-            # feature stream (clamped once the candidate phase starts)
-            pl.BlockSpec((1, block, d // 2),
-                         lambda bh, j, nl, po: (bh, jnp.minimum(j, nb - 1),
-                                                0)),
-            # exact caches stay in HBM; candidates are DMA'd by block id
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=out_specs if return_stats else out_specs[0],
-        scratch_shapes=[
-            pltpu.VMEM((g_sel, nbp), jnp.float32),   # block scores
-            pltpu.VMEM((g_sel, nbp), jnp.int32),     # emission ranks
-            pltpu.VMEM((g, 128), jnp.float32),       # running max
-            pltpu.VMEM((g, 128), jnp.float32),       # running sum-exp
-            pltpu.VMEM((g, d), jnp.float32),         # output accumulator
-            pltpu.VMEM((n_slots, block, d), jnp.int8),     # K block slots
-            pltpu.VMEM((n_slots, block, d), jnp.int8),     # V block slots
-            pltpu.VMEM((n_slots, block, 1), jnp.float32),  # K scale slots
-            pltpu.VMEM((n_slots, block, 1), jnp.float32),  # V scale slots
-            pltpu.SemaphoreType.DMA((n_slots, 4)),
-        ],
-    )
+    rank = pl.pallas_call(
+        functools.partial(_lop_screen_kernel, nb=nb, hkv=hkv, block=block,
+                          k_keep=k_keep, window=window, n_buckets=n_buckets,
+                          shared_select=shared_select),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bhg, nb),
+            in_specs=[
+                pl.BlockSpec((1, g, d), lane),
+                pl.BlockSpec((1, block, d // 2), lambda bh, j, *_: (bh, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g_sel, nbp), lane),
+            scratch_shapes=[pltpu.VMEM((g_sel, nbp), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bhg, g_sel, nbp), jnp.int32),
+        interpret=interpret,
+    )(new_len, pos_off, qi, feat)
+    ids, gate = _candidates(rank, k_keep, nb)
+
+    # the pipeline fetches candidate c's block by the prefetched id; with
+    # two buffers the next fetch is in flight behind the MXU work of the
+    # current candidate — the head-level pipelining the paper overlaps in
+    # silicon. The buffer count only changes WHEN a block is fetched,
+    # never which blocks fold or in what order, so n_slots = 1 is bitwise
+    # n_slots = 2 (DESIGN.md §Autotuning).
+    n_cand = k_keep * g_sel
+    cand = lambda bh, c, nl, po, ids_, gate_: (bh, ids_[bh * n_cand + c], 0)
+    buffered = pl.Buffered(n_slots)
     return pl.pallas_call(
-        functools.partial(_fused_lop_kernel, nb=nb, g=g, hkv=hkv,
+        functools.partial(_lop_exact_kernel, g=g, n_cand=n_cand, hkv=hkv,
                           block=block, k_keep=k_keep, window=window,
-                          softmax_scale=softmax_scale, n_buckets=n_buckets,
-                          n_slots=n_slots, shared_select=shared_select,
+                          softmax_scale=softmax_scale,
+                          shared_select=shared_select,
                           return_stats=return_stats),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bhg, n_cand),
+            in_specs=[
+                pl.BlockSpec((1, g, d), lane),
+                pl.BlockSpec((1, g, 1), lane),
+                pl.BlockSpec((1, block, d), cand, pipeline_mode=buffered),
+                pl.BlockSpec((1, block, d), cand, pipeline_mode=buffered),
+                pl.BlockSpec((1, block, 1), cand, pipeline_mode=buffered),
+                pl.BlockSpec((1, block, 1), cand, pipeline_mode=buffered),
+            ],
+            out_specs=out_specs if return_stats else out_specs[0],
+            scratch_shapes=softmax_scratch,
+        ),
         out_shape=outs if return_stats else outs[0],
         interpret=interpret,
-    )(new_len, pos_off, qi, qsc, feat, k_cache, v_cache, k_scale, v_scale)
+    )(new_len, pos_off, ids, gate, qi, qsc, k_cache, v_cache, k_scale,
+      v_scale)

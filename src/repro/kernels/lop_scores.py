@@ -31,12 +31,17 @@ LO_ZERO = 7
 
 
 def _nibbles_to_pot(feat_packed: jax.Array, d: int) -> jax.Array:
-    """uint8 [bm, d//2] packed (sgn‖LO) nibbles → int8 pot values [bm, d]."""
-    lo_nib = feat_packed & 0xF
-    hi_nib = (feat_packed >> 4) & 0xF
+    """uint8 [bm, d//2] packed (sgn‖LO) nibbles → int8 pot values [bm, d].
+
+    Everything up to the final cast runs in int32: Mosaic cannot shift or
+    lane-interleave int8 vectors.
+    """
+    fp = feat_packed.astype(jnp.int32)
+    lo_nib = fp & 0xF
+    hi_nib = (fp >> 4) & 0xF
     nib = jnp.stack([lo_nib, hi_nib], axis=-1).reshape(feat_packed.shape[0], d)
-    lo = (nib & 0x7).astype(jnp.int32)
-    sgn = ((nib >> 3) & 0x1).astype(jnp.int32)
+    lo = nib & 0x7
+    sgn = (nib >> 3) & 0x1
     mag = jnp.where(lo == LO_ZERO, 0, jnp.left_shift(1, jnp.minimum(lo, 6)))
     return ((1 - 2 * sgn) * mag).astype(jnp.int8)
 

@@ -4,9 +4,11 @@ Dispatch policy
 ---------------
 Every op has two interchangeable implementations with identical semantics:
 
-  * ``pallas``  — the TPU kernel (``interpret=True`` on CPU, where the kernel
-    body executes in Python; this is the validation mode mandated for this
-    container).
+  * ``pallas``  — the TPU kernel, compiled by Mosaic on a TPU. Elsewhere it
+    runs with ``interpret=True``, the kernel body executing in Python:
+    the CPU test path. ``tests/test_chip_compile.py`` compiles the
+    served kernels with Mosaic for a described v5e, which catches what
+    interpret mode accepts and Mosaic refuses.
   * ``ref``     — the pure-jnp oracle in :mod:`repro.kernels.ref`. XLA lowers
     it to the same MXU int8 dots on TPU; it is also what the full-size
     dry-run traces (interpret-mode Pallas unrolls its grid at trace time,
@@ -35,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lop import pot
+from repro.core.quantization import rmsnorm as rmsnorm_ref
 from repro.core.ternary import TernaryWeight
 from repro.kernels import autotune as _tune
 from repro.kernels import decode_attention as _dec
@@ -43,6 +46,7 @@ from repro.kernels import lop_scores as _lop
 from repro.kernels import prefill_attention as _pf
 from repro.kernels import qlinear as _ql
 from repro.kernels import ref as _ref
+from repro.kernels import rmsnorm as _rms
 from repro.kernels import ternary_matmul as _tmm
 
 
@@ -75,6 +79,45 @@ def _pad_to(x: jax.Array, mult: int, axis: int):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths), size
+
+
+# ---------------------------------------------------------------------------
+# Row-invariant ops on fixed row tiles
+#
+# XLA compiles a reduction or matmul per operand shape, and on a TPU v5e
+# neither is row-invariant: bitnet-3b's layer-0 RMSNorm rows differed
+# between a 300-row whole-prompt call and a 128-row prefill chunk, and
+# the LM head's logits between 1-row and 4-row calls. The serving path
+# needs a row's value to depend on that row alone — a whole-prompt
+# prefill, a prefill chunk and a decode step of any lane count must
+# agree bit for bit, or a pool lane's greedy tokens drift from the
+# batch-1 reference — so these ops run one fixed program per
+# ``ROW_TILE``-row tile, whatever the number of rows.
+# ---------------------------------------------------------------------------
+
+ROW_TILE = 8              # one f32 sublane tile
+
+
+def map_row_tiles(fn, x: jax.Array) -> jax.Array:
+    """``fn`` on zero-padded [ROW_TILE, d] tiles of x [..., d], one tile
+    at a time (one compiled body) → [..., n_out]."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    xp, n = _pad_to(x.reshape(-1, d), ROW_TILE, 0)
+    y = jax.lax.map(fn, xp.reshape(-1, ROW_TILE, d))
+    return y.reshape(xp.shape[0], -1)[:n].reshape(*lead, -1)
+
+
+def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6, *,
+            impl: str = "auto") -> jax.Array:
+    """RMSNorm over the last axis of f32 x [..., d] with γ [d]; under
+    ``pallas`` a Pallas grid over ``ROW_TILE``-row tiles."""
+    if _resolve(impl) == "ref":
+        return rmsnorm_ref(x, gamma, eps)
+    d = x.shape[-1]
+    xp, n = _pad_to(x.reshape(-1, d).astype(jnp.float32), ROW_TILE, 0)
+    out = _rms.rmsnorm_rows(xp, gamma.astype(jnp.float32).reshape(1, d),
+                            eps=eps, rows=ROW_TILE, interpret=_interpret())
+    return out[:n].reshape(x.shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -115,16 +158,6 @@ def ternary_matmul(x: jax.Array, tw: TernaryWeight, *,
 # §TINT-projection-fusion): the absmax barrier, the packed-ternary GEMM
 # and the dequant/bias/activation epilogue run as ONE dispatch.
 # ---------------------------------------------------------------------------
-
-def _pick_block(n: int, target: int = 128) -> int:
-    """Largest divisor of n that is ≤ target (no weight-column padding)."""
-    if n <= target:
-        return n
-    for b in range(target, 0, -1):
-        if n % b == 0:
-            return b
-    return 1
-
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
@@ -172,11 +205,11 @@ def qlinear_fused(x: jax.Array, packed: jax.Array, scale: jax.Array,
     tuned = _tune.lookup("qlinear", {"e": x3.shape[0], "m": m0,
                                      "k": k, "n": n})
     bm = tuned.get("bm", min(_tmm.DEFAULT_BM, _round_up(max(m0, 1), 8)))
+    bn = tuned.get("bn") or _tune.pick_block(n, k // 4)
     pad = (-m0) % bm
     if pad:
         x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
-    out = _ql.fused_qlinear(x3, p3, s3, b3, bm=bm,
-                            bn=tuned.get("bn", _pick_block(n)),
+    out = _ql.fused_qlinear(x3, p3, s3, b3, bm=bm, bn=bn,
                             bkq=tuned.get("bkq", 0),
                             eg=tuned.get("eg", 1),
                             act=act, interpret=_interpret())[:, :m0]
@@ -220,12 +253,13 @@ def ffn_fused(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
     tuned = _tune.lookup("ffn", {"e": x3.shape[0], "m": m0, "k": k,
                                  "f": f, "n": d_out})
     bm = tuned.get("bm", min(_tmm.DEFAULT_BM, _round_up(max(m0, 1), 8)))
+    # gated, gate ‖ up share one 2f-wide array: f is not its whole dim
+    bf = tuned.get("bf") or _tune.pick_block(f, None if gated else k // 4)
+    bn = tuned.get("bn") or _tune.pick_block(d_out, f // 4)
     pad = (-m0) % bm
     if pad:
         x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
-    out = _ql.fused_ffn(x3, gu3, gs3, d3, ds3, bm=bm,
-                        bf=tuned.get("bf", _pick_block(f)),
-                        bn=tuned.get("bn", _pick_block(d_out)),
+    out = _ql.fused_ffn(x3, gu3, gs3, d3, ds3, bm=bm, bf=bf, bn=bn,
                         bkq=tuned.get("bkq", 0), act=act, gated=gated,
                         interpret=_interpret())[:, :m0]
     if expert:

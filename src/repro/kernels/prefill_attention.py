@@ -51,11 +51,13 @@ chunk's K/V were written (0 = nothing valid → zero output); ``q_off``
 int32 [1] — global position of chunk row t = 0. They drive gating and
 masking only; tensor operands are addressed by the grid alone.
 
-Validated in interpret mode (the container's mandated mode). The R
+Interpret mode is the CPU test path; ``tests/test_chip_compile.py``
+compiles the kernel with Mosaic for a v5e at bitnet-3b widths. The R
 query rows can be tiled over a third grid axis (``bq``, the
 carried-forward ROADMAP.md item, now a sweepable knob of
 DESIGN.md §Autotuning): each bq-row slab walks the same kv blocks the
-untiled kernel walks, so any divisor of R is bitwise ``bq = R`` while
+untiled kernel walks, so any legal ``bq`` (a divisor of R that is a
+multiple of the 8-row sublane tile) is bitwise ``bq = R`` while
 capping resident VMEM at bq·(256 + d) f32 — what makes whole-prompt
 32k-row calls compilable on real hardware.
 """
@@ -149,8 +151,10 @@ def _fused_prefill_kernel(kvl_ref, qo_ref, qi_ref, qs_ref, k_ref, v_ref,
         p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         l_ref[...] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
         v_deq = v_ref[0].astype(jnp.float32) * vs_ref[0]
+        # HIGHEST: Mosaic's default f32 dot rounds its operands to bf16
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
-            p, v_deq, preferred_element_type=jnp.float32)
+            p, v_deq, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == nb - 1)

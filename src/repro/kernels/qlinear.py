@@ -335,6 +335,9 @@ def fused_ffn(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
     def _down_idx(e, i, j):
         return (e, 0, jnp.clip(j - nk2 - nf, 0, nd - 1))
 
+    def _out_idx(e, i, j):
+        return (e, i, jnp.clip(j - nk2 - nf, 0, nd - 1))
+
     in_specs = [
         pl.BlockSpec((1, bm, bkq if bkq else k), _x_idx),
         pl.BlockSpec((1, k // 4, bf), _up_idx),
@@ -370,7 +373,7 @@ def fused_ffn(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
                           nf=nf, nd=nd, act=act, gated=gated),
         grid=(e, m // bm, nk2 + nf + nd),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bm, bn), _down_idx),
+        out_specs=pl.BlockSpec((1, bm, bn), _out_idx),
         out_shape=jax.ShapeDtypeStruct((e, m, d_out), jnp.float32),
         scratch_shapes=scratch_shapes,
         interpret=interpret,

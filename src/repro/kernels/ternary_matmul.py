@@ -29,12 +29,18 @@ DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 512, 128
 
 
 def _unpack_codes(wp: jax.Array, bk: int) -> jax.Array:
-    """uint8 codes [bk//4, bn] → int8 ternary [bk, bn] (in-VMEM unpack)."""
-    parts = [(wp >> (2 * j)) & 0x3 for j in range(4)]          # each [bk//4, bn]
+    """uint8 codes [bk//4, bn] → int8 ternary [bk, bn] (in-VMEM unpack).
+
+    Shifts, masks, the row interleave and the subtraction run in int32:
+    Mosaic has no int8 vector shift or subtract. One cast to int8 right
+    before the MXU dot.
+    """
+    w = wp.astype(jnp.int32)
+    parts = [(w >> (2 * j)) & 0x3 for j in range(4)]           # each [bk//4, bn]
     codes = jnp.stack(parts, axis=1).reshape(bk, wp.shape[-1])
-    pos = (codes == 1).astype(jnp.int8)
-    neg = (codes == 2).astype(jnp.int8)
-    return pos - neg
+    pos = (codes == 1).astype(jnp.int32)
+    neg = (codes == 2).astype(jnp.int32)
+    return (pos - neg).astype(jnp.int8)
 
 
 def _ternary_matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, n_k: int):

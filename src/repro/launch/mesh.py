@@ -10,17 +10,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and ``make_mesh`` accepts
-    ``axis_types``; older releases (e.g. 0.4.x) have neither — every axis
-    is implicitly Auto there, so plain ``make_mesh`` is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

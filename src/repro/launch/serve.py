@@ -55,11 +55,11 @@ import jax
 import numpy as np
 
 from repro.core.lop import kv_traffic_bytes
+from repro.launch import compile_cache
 from repro.launch.train import resolve_config
-from repro.models.transformer import init_params
 from repro.serving import metrics as smetrics
 from repro.serving.api import GenerateRequest, SamplingParams, StepResult
-from repro.serving.quantize import quantize_params
+from repro.serving.quantize import build_serving_params
 from repro.serving.scheduler import Scheduler, lockstep_generate
 
 
@@ -154,8 +154,7 @@ def serve_loop(cfg, *, n_slots: int = 4, n_requests: int = 8,
     if engine is not None:
         cfg, qp = engine.cfg, engine.qp
     else:
-        params, _ = init_params(cfg, jax.random.PRNGKey(seed))
-        qp = quantize_params(cfg, params)
+        qp = build_serving_params(cfg, jax.random.PRNGKey(seed))
     reqs = make_requests(cfg, n_requests=n_requests, min_prompt=min_prompt,
                          max_prompt=max_prompt, gen=gen, seed=seed + 1,
                          sampling=sampling,
@@ -353,6 +352,7 @@ def main():
                     help="replay each request alone (lockstep, same "
                          "SamplingParams) and check token-exact agreement")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = resolve_config(args.arch, args.reduced)
     sampling = SamplingParams(temperature=args.temperature,

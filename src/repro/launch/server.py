@@ -24,19 +24,18 @@ import asyncio
 
 import jax
 
+from repro.launch import compile_cache
 from repro.launch.train import resolve_config
-from repro.models.transformer import init_params
 from repro.serving.api import PooledEngine
 from repro.serving.frontend import HttpFrontend
 from repro.serving.metrics import REGISTRY
-from repro.serving.quantize import quantize_params
+from repro.serving.quantize import build_serving_params
 from repro.serving.scheduler import Scheduler
 
 
 def build_scheduler(args) -> Scheduler:
     cfg = resolve_config(args.arch, args.reduced)
-    params, _ = init_params(cfg, jax.random.PRNGKey(args.seed))
-    qp = quantize_params(cfg, params)
+    qp = build_serving_params(cfg, jax.random.PRNGKey(args.seed))
     engine = PooledEngine(cfg, qp, max_len=args.max_len,
                           use_lop=not args.no_lop,
                           chunk_tokens=args.chunk_tokens,
@@ -65,7 +64,7 @@ async def amain(args) -> None:
     await frontend.serve_forever()
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="bitnet-3b")
     ap.add_argument("--reduced", action="store_true")
@@ -91,7 +90,12 @@ def main() -> None:
     ap.add_argument("--shape-log", default=None,
                     help="JSON sidecar recording kernel dispatch shapes "
                          "for `repro.kernels.autotune --from-log`")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    compile_cache.enable()
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

@@ -152,43 +152,55 @@ def rwkv_layer_apply(cfg, p, x):
 # Full-model init
 # ---------------------------------------------------------------------------
 
-def init_params(cfg, key):
-    """Returns (params, pspecs) for any family."""
+def init_unstacked(cfg, key):
+    """(params, pspecs) of every leaf of ``init_params(cfg, key)`` that is
+    not stacked over layers: embedding, head and final norms."""
     keys = jax.random.split(key, 8)
     p, s = {}, {}
     p["embed"], s["embed"] = embedding_init(keys[0], cfg.vocab_padded,
                                             cfg.d_model)
     p["ln_f"], s["ln_f"] = norm_init(cfg.d_model, cfg.norm)
     p["head"], s["head"] = linear_init(keys[1], cfg.d_model, cfg.vocab_padded)
-
-    if cfg.family in ("dense", "moe", "vlm"):
-        moe = cfg.family == "moe"
-        p["layers"], s["layers"] = stacked_init(
-            functools.partial(decoder_layer_init, cfg=cfg, moe=moe),
-            keys[2], cfg.n_layers)
-        if cfg.family == "vlm":
-            p["projector"], s["projector"] = linear_init(
-                keys[3], cfg.d_model, cfg.d_model, spec=("fsdp", "tp"))
-    elif cfg.family == "hybrid":
-        assert cfg.n_layers % cfg.attn_every == 0
-        p["blocks"], s["blocks"] = stacked_init(
-            functools.partial(superblock_init, cfg=cfg),
-            keys[2], cfg.n_layers // cfg.attn_every)
-    elif cfg.family == "ssm":
-        p["layers"], s["layers"] = stacked_init(
-            functools.partial(rwkv_layer_init, cfg=cfg),
-            keys[2], cfg.n_layers)
-    elif cfg.family == "encdec":
-        p["enc_layers"], s["enc_layers"] = stacked_init(
-            functools.partial(decoder_layer_init, cfg=cfg, moe=False),
-            keys[2], cfg.n_encoder_layers)
+    if cfg.family == "vlm":
+        p["projector"], s["projector"] = linear_init(
+            keys[3], cfg.d_model, cfg.d_model, spec=("fsdp", "tp"))
+    if cfg.family == "encdec":
         p["ln_enc"], s["ln_enc"] = norm_init(cfg.d_model, cfg.norm)
-        p["layers"], s["layers"] = stacked_init(
-            functools.partial(decoder_layer_init, cfg=cfg, moe=False,
-                              cross=True),
-            keys[3], cfg.n_layers)
-    else:
-        raise ValueError(cfg.family)
+    return p, s
+
+
+def stacked_groups(cfg, key):
+    """The layer-stacked groups of ``init_params(cfg, key)``: name →
+    (per-layer init, group key, layer count). ``stacked_init`` splits the
+    group key into one key per layer."""
+    keys = jax.random.split(key, 8)
+    if cfg.family in ("dense", "moe", "vlm"):
+        return {"layers": (functools.partial(
+            decoder_layer_init, cfg=cfg, moe=cfg.family == "moe"),
+            keys[2], cfg.n_layers)}
+    if cfg.family == "hybrid":
+        assert cfg.n_layers % cfg.attn_every == 0
+        return {"blocks": (functools.partial(superblock_init, cfg=cfg),
+                           keys[2], cfg.n_layers // cfg.attn_every)}
+    if cfg.family == "ssm":
+        return {"layers": (functools.partial(rwkv_layer_init, cfg=cfg),
+                           keys[2], cfg.n_layers)}
+    if cfg.family == "encdec":
+        return {
+            "enc_layers": (functools.partial(decoder_layer_init, cfg=cfg,
+                                             moe=False),
+                           keys[2], cfg.n_encoder_layers),
+            "layers": (functools.partial(decoder_layer_init, cfg=cfg,
+                                         moe=False, cross=True),
+                       keys[3], cfg.n_layers)}
+    raise ValueError(cfg.family)
+
+
+def init_params(cfg, key):
+    """Returns (params, pspecs) for any family."""
+    p, s = init_unstacked(cfg, key)
+    for name, (layer_init, gkey, n) in stacked_groups(cfg, key).items():
+        p[name], s[name] = stacked_init(layer_init, gkey, n)
     return p, s
 
 

@@ -93,6 +93,15 @@ def _q(x, axis=-1):
     return qt.values, qt.scale
 
 
+def _norm(params, x, kind: str):
+    """``norm_apply`` with RMSNorm through the row-invariant
+    :func:`repro.kernels.ops.rmsnorm` (whole prompt, prefill chunk and
+    decode lanes agree row for row)."""
+    if kind == "rmsnorm":
+        return ops.rmsnorm(x, params["g"])
+    return norm_apply(params, x, kind)
+
+
 def _shard_batch(x, *rest):
     """Constrain batch over dp only when it divides (long_500k has B=1)."""
     mesh = current_mesh()
@@ -414,7 +423,7 @@ def cross_attn_decode(cfg, lp, h, cross_cl, cross_len, *, use_lop=True,
 # ===========================================================================
 
 def _mlp(cfg, lp, x):
-    h = norm_apply(lp["ln2"], x, cfg.norm)
+    h = _norm(lp["ln2"], x, cfg.norm)
     if "moe" in lp:
         y, _ = moe_apply(cfg, lp["moe"], h)
     else:
@@ -425,13 +434,13 @@ def _mlp(cfg, lp, x):
 def _decoder_layer_prefill(cfg, lp, x, *, capacity, enc=None, cross_cap=None,
                            cross_len=None):
     x = _shard_batch(x)
-    h = norm_apply(lp["ln1"], x, cfg.norm)
+    h = _norm(lp["ln1"], x, cfg.norm)
     attn_out, cache_l = attn_prefill(cfg, lp["attn"], h, capacity=capacity)
     x = x + attn_out
     out = {"self": cache_l}
     if enc is not None:
         cross_cache = build_cross_cache(cfg, lp["xattn"], enc, cross_cap)
-        h = norm_apply(lp["ln_x"], x, cfg.norm)
+        h = _norm(lp["ln_x"], x, cfg.norm)
         x = x + cross_attn_prefill(
             cfg, lp["xattn"], h, cross_cache, cross_len,
             kv_max=min(cross_cap, round_up(enc.shape[1], cfg.lop_block)))
@@ -443,13 +452,13 @@ def _decoder_layer_decode(cfg, lp, x, cl, lengths, *, use_lop, sp_axes,
                           cross_cl=None, cross_len=None, active=None,
                           k_keep=None):
     x = _shard_batch(x)
-    h = norm_apply(lp["ln1"], x, cfg.norm)
+    h = _norm(lp["ln1"], x, cfg.norm)
     attn_out, new_cl = attn_decode(cfg, lp["attn"], h, cl, lengths,
                                    use_lop=use_lop, sp_axes=sp_axes,
                                    active=active, k_keep=k_keep)
     x = x + attn_out
     if cross_cl is not None:
-        h = norm_apply(lp["ln_x"], x, cfg.norm)
+        h = _norm(lp["ln_x"], x, cfg.norm)
         x = x + cross_attn_decode(cfg, lp["xattn"], h, cross_cl, cross_len,
                                   use_lop=use_lop, sp_axes=sp_axes)
     return _mlp(cfg, lp, x), new_cl
@@ -457,14 +466,14 @@ def _decoder_layer_decode(cfg, lp, x, cl, lengths, *, use_lop, sp_axes,
 
 def _mamba_layer_prefill(cfg, lp, x):
     x = _shard_batch(x)
-    h = norm_apply(lp["ln1"], x, cfg.norm)
+    h = _norm(lp["ln1"], x, cfg.norm)
     y, state = mamba_forward(cfg, lp["mamba"], h)
     return _mlp(cfg, lp, x + y), state
 
 
 def _mamba_layer_decode(cfg, lp, x, state):
     x = _shard_batch(x)
-    h = norm_apply(lp["ln1"], x, cfg.norm)
+    h = _norm(lp["ln1"], x, cfg.norm)
     y, state = mamba_decode_step(cfg, lp["mamba"], h, state)
     return _mlp(cfg, lp, x + y), state
 
@@ -472,11 +481,11 @@ def _mamba_layer_decode(cfg, lp, x, state):
 def _rwkv_layer(cfg, lp, x, st):
     """Works for both prefill (T=S, zero states in st) and decode (T=1)."""
     x = _shard_batch(x)
-    h = norm_apply(lp["ln1"], x, cfg.norm)
+    h = _norm(lp["ln1"], x, cfg.norm)
     y, x_tm, wkv = rwkv6.rwkv_time_mix(cfg, lp["tm"], h, st["x_tm"],
                                        st["wkv"])
     x = x + y
-    h = norm_apply(lp["ln2"], x, cfg.norm)
+    h = _norm(lp["ln2"], x, cfg.norm)
     y, x_cm = rwkv6.rwkv_channel_mix(cfg, lp["tm"], h, st["x_cm"])
     return x + y, {"wkv": wkv, "x_tm": x_tm, "x_cm": x_cm}
 
@@ -494,8 +503,12 @@ def _embed(cfg, qp, tokens, patches=None):
 
 
 def _logits(cfg, qp, x_last):
-    x = norm_apply(qp["ln_f"], x_last, cfg.norm)
-    return head_apply(qp["head"], x)
+    """Final norm + f32 LM head, the head on fixed row tiles
+    (:func:`repro.kernels.ops.map_row_tiles`): a pool lane's greedy token
+    must equal the batch-1 lockstep path's (and the speculative verify
+    rows'), bitwise, whatever the number of rows in the call."""
+    x = _norm(qp["ln_f"], x_last, cfg.norm)
+    return ops.map_row_tiles(lambda r: head_apply(qp["head"], r), x)
 
 
 def prefill(cfg, qp, tokens, *, frames=None, patches=None, max_len=None,
@@ -571,7 +584,7 @@ def prefill(cfg, qp, tokens, *, frames=None, patches=None, max_len=None,
 
         def enc_body(e, lp):
             e = _shard_batch(e)
-            h = norm_apply(lp["ln1"], e, cfg.norm)
+            h = _norm(lp["ln1"], e, cfg.norm)
             q, k, v = _project_qkv(cfg, lp["attn"], h)
             qi, qsc = _q(q)
             ki, vi, ksc, vsc, _ = _quantize_kv(k, v)
@@ -586,7 +599,7 @@ def prefill(cfg, qp, tokens, *, frames=None, patches=None, max_len=None,
             return _mlp(cfg, lp, e), None
 
         enc, _ = _layer_scan(enc_body, enc, qp["enc_layers"])
-        enc = norm_apply(qp["ln_enc"], enc, cfg.norm)
+        enc = _norm(qp["ln_enc"], enc, cfg.norm)
         cross_len = jnp.full((b,), enc.shape[1], jnp.int32)
 
         def body(x, lp):
@@ -657,7 +670,7 @@ def prefill_chunk(cfg, qp, tokens, cache, *, start, seq_end, patches=None,
     def body(x, inp):
         lp, cl = inp
         x = _shard_batch(x)
-        h = norm_apply(lp["ln1"], x, cfg.norm)
+        h = _norm(lp["ln1"], x, cfg.norm)
         attn_out, ncl = attn_prefill_chunk(cfg, lp["attn"], h, cl,
                                            start=start, kv_len=kv_len)
         return _mlp(cfg, lp, x + attn_out), ncl

@@ -23,7 +23,7 @@ weights so one kernel dispatch replaces several:
     "down_packed", "down_scale"}`` — gate‖up codes share one stream and
     the down projection rides the SAME launch
     (:func:`repro.kernels.ops.ffn_fused`), hidden state never touching
-    HBM,
+    HBM; the hidden width is zero-padded to ``FFN_HIDDEN_ALIGN``,
   * MoE expert stacks [E, k, n] fuse the same way with a leading expert
     axis — the whole MoE layer's expert FFNs are ONE grouped dispatch.
 
@@ -46,6 +46,12 @@ import jax.numpy as jnp
 from repro.core.qlinear import (is_fused_ffn, is_packed, qlinear,  # noqa: F401 (re-export)
                                 qlinear_expert)
 from repro.core.ternary import pack_ternary, ternary_quantize
+
+# The fused FFN kernel streams hidden columns in 128-lane blocks, so the
+# hidden width f is padded with zero codes to a multiple of this. A zero
+# column leaves the hidden vector and its absmax unchanged (every
+# activation maps 0 to 0), so the padded FFN is bitwise the unpadded one.
+FFN_HIDDEN_ALIGN = 128
 
 # param-path names that stay high-precision even when 2-D
 _KEEP_FP = ("head", "projector", "router", "mu", "mu_c", "u",
@@ -93,6 +99,15 @@ def _concat_packed(parts):
     out = {"packed": packed, "scale": scale}
     _check_packed(out, packed.shape[-2] * 4)
     return out
+
+
+def _pad_codes(node, size: int, axis: int):
+    """Zero-code padding of a packed node's ``axis`` to ``size`` (γ is
+    one scalar per stream, so the scale needs none)."""
+    packed = node["packed"]
+    widths = [(0, 0)] * packed.ndim
+    widths[axis] = (0, size - packed.shape[axis])
+    return dict(node, packed=jnp.pad(packed, widths))
 
 
 def _eligible(name: str, k: int, quant: str) -> bool:
@@ -153,10 +168,11 @@ def _fuse_ffn(node, quant: str):
             and wu.shape[-1] == f and (not gated or wg.shape[-2:] ==
                                        wu.shape[-2:])):
         return None
-    parts = ([_quantize_linear(wg)] if gated else []) \
-        + [_quantize_linear(wu)]
+    fp = -(-f // FFN_HIDDEN_ALIGN) * FFN_HIDDEN_ALIGN
+    parts = [_pad_codes(_quantize_linear(w), fp, axis=-1)
+             for w in ([wg] if gated else []) + [wu]]
     gu = _concat_packed(parts)
-    down = _quantize_linear(wd)
+    down = _pad_codes(_quantize_linear(wd), fp // 4, axis=-2)
     out = {key: val for key, val in node.items() if key not in
            ("w_gate", "w_up", "w_down")}           # router etc. stay fp
     out.update({"gu_packed": gu["packed"], "gu_scale": gu["scale"],
@@ -201,3 +217,29 @@ def quantize_params(cfg, params, *, fuse: bool = True):
         return node
 
     return walk((), params)
+
+
+def build_serving_params(cfg, key):
+    """The serving tree of ``init_params(cfg, key)``, built without its
+    f32 master tree.
+
+    Bitwise ``quantize_params(cfg, init_params(cfg, key)[0])`` under
+    jit, but the f32 weights of at most one layer exist at a time: the
+    unstacked leaves are made and quantized under one jit, then each
+    layer of each stacked group under another, from the key
+    ``stacked_init`` splits for that layer. The master tree of bitnet-3b
+    alone is 13.7 GB, near all of one 16 GB chip.
+    """
+    from repro.models.transformer import init_unstacked, stacked_groups
+
+    qp = jax.jit(lambda k: quantize_params(cfg, init_unstacked(cfg, k)[0]))(
+        key)
+    for name, (layer_init, gkey, n) in stacked_groups(cfg, key).items():
+        def one(k, name=name, layer_init=layer_init):
+            layer = jax.tree.map(lambda a: a[None], layer_init(k)[0])
+            return quantize_params(cfg, {name: layer})[name]
+        one = jax.jit(one)
+        layers = [one(k) for k in jax.random.split(gkey, n)]
+        qp[name] = jax.tree.map(lambda *xs: jnp.concatenate(xs), *layers)
+        del layers
+    return qp

@@ -11,7 +11,6 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.collective_matmul import (allgather_matmul,
                                                  ring_reduce_matmul)
 from repro.distributed.compression import compressed_psum, init_error_state
-from repro.distributed.partitioning import shard_map
 from repro.launch.mesh import make_host_mesh
 
 rng = np.random.default_rng(0)
@@ -25,7 +24,7 @@ def one_round(x, err):
     return compressed_psum(x, "data", err)
 
 
-f = shard_map(one_round, mesh=mesh, in_specs=(P("data"), P("data")),
+f = jax.shard_map(one_round, mesh=mesh, in_specs=(P("data"), P("data")),
                   out_specs=(P("data"), P("data")), check_vma=False)
 err0 = jnp.zeros_like(x)
 total, err1 = f(x, err0)
@@ -59,7 +58,7 @@ def ring(xl, wl):
     return ring_reduce_matmul(xl[0], wl[0], "data", chunks=4)[None]
 
 
-g = shard_map(ring, mesh=mesh, in_specs=(P("data"), P("data")),
+g = jax.shard_map(ring, mesh=mesh, in_specs=(P("data"), P("data")),
                   out_specs=P("data"), check_vma=False)
 y_ring = g(x_loc, w_loc)[0]
 y_ref = sum(np.asarray(x_loc[i]) @ np.asarray(w_loc[i]) for i in range(8))
@@ -75,7 +74,7 @@ def ag(xl, wl):
     return allgather_matmul(xl, wl, "data")
 
 
-h = shard_map(ag, mesh=mesh, in_specs=(P("data"), P(None, None)),
+h = jax.shard_map(ag, mesh=mesh, in_specs=(P("data"), P(None, None)),
                   out_specs=P(None, None), check_vma=False)
 y_ag = h(x_batch, w_full)
 y_exp = np.asarray(x_batch) @ np.asarray(w_full)

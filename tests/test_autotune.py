@@ -93,19 +93,22 @@ def test_config_key_tracks_backend():
 
 
 @pytest.mark.parametrize("kernel,params,ok", [
-    ("qlinear", {"bm": 8, "bn": 32, "bkq": 32, "eg": 2}, True),
-    ("qlinear", {"bm": 12, "bn": 32}, False),       # bm not a multiple of 8
+    ("qlinear", {"bm": 8, "bn": 128, "bkq": 128, "eg": 2}, True),
+    ("qlinear", {"bm": 12, "bn": 128}, False),      # bm not a multiple of 8
     ("qlinear", {"bn": 7}, False),                  # 7 does not divide n
+    ("qlinear", {"bn": 32}, False),                 # not a 128-lane block
     ("qlinear", {"bkq": 24}, False),                # 24 does not divide k
     ("qlinear", {"eg": 3}, False),                  # 3 does not divide e
     ("qlinear", {"nope": 1}, False),                # unknown knob
     ("qlinear", "bm=8", False),                     # not a dict
-    ("ffn", {"bm": 8, "bf": 64, "bn": 32, "bkq": 0}, True),
+    ("ffn", {"bm": 8, "bf": 128, "bn": 256, "bkq": 0}, True),
     ("ffn", {"bf": 7}, False),
+    ("ffn", {"bf": 64}, False),                     # not a 128-lane block
     ("prefill", {"block": 32, "bq": 8}, True),
     ("prefill", {"block": 32, "bq": 7}, False),     # 7 does not divide r
     ("prefill", {"block": 0}, False),
-    ("decode", {"n_slots": 4}, True),
+    ("decode", {"n_slots": 1}, True),
+    ("decode", {"n_slots": 4}, False),              # Mosaic buffers ≤ 2
     ("decode", {"n_slots": 0}, False),
     ("ternary_matmul", {"bm": 8, "bk": 64, "bn": 32}, True),
     ("ternary_matmul", {"bk": 7}, False),
@@ -120,7 +123,7 @@ def test_valid_params_screen(kernel, params, ok):
 # Table I/O + lookup precedence
 # ---------------------------------------------------------------------------
 
-QDIMS = {"e": 1, "m": 8, "k": 64, "n": 64}
+QDIMS = {"e": 1, "m": 8, "k": 256, "n": 256}
 
 
 def _mk_table(tmp_path, monkeypatch, params, *, us=1.0):
@@ -135,33 +138,34 @@ def _mk_table(tmp_path, monkeypatch, params, *, us=1.0):
 
 
 def test_lookup_hits_table_misses_other_shapes(tmp_path, monkeypatch):
-    _mk_table(tmp_path, monkeypatch, {"bm": 8, "bn": 32, "bkq": 32, "eg": 1})
+    _mk_table(tmp_path, monkeypatch, {"bm": 8, "bn": 128, "bkq": 128,
+                                      "eg": 1})
     assert autotune.lookup("qlinear", QDIMS) == \
-        {"bm": 8, "bn": 32, "bkq": 32, "eg": 1}
+        {"bm": 8, "bn": 128, "bkq": 128, "eg": 1}
     other = dict(QDIMS, m=16)
     assert autotune.lookup("qlinear", other) == {}
     assert autotune.lookup("ffn", DIMS["ffn"]) == {}
 
 
 def test_repro_tune_0_disables_the_table(tmp_path, monkeypatch):
-    _mk_table(tmp_path, monkeypatch, {"bn": 32})
+    _mk_table(tmp_path, monkeypatch, {"bn": 128})
     monkeypatch.setenv("REPRO_TUNE", "0")
     assert autotune.lookup("qlinear", QDIMS) == {}
 
 
 def test_override_beats_table_and_restores(tmp_path, monkeypatch):
-    _mk_table(tmp_path, monkeypatch, {"bn": 32})
-    with autotune.override("qlinear", bm=8, bn=64):
-        assert autotune.lookup("qlinear", QDIMS) == {"bm": 8, "bn": 64}
-        with autotune.override("qlinear", bn=16):
-            assert autotune.lookup("qlinear", QDIMS) == {"bn": 16}
-        assert autotune.lookup("qlinear", QDIMS) == {"bm": 8, "bn": 64}
-    assert autotune.lookup("qlinear", QDIMS) == {"bn": 32}
+    _mk_table(tmp_path, monkeypatch, {"bn": 128})
+    with autotune.override("qlinear", bm=8, bn=256):
+        assert autotune.lookup("qlinear", QDIMS) == {"bm": 8, "bn": 256}
+        with autotune.override("qlinear", bm=16):
+            assert autotune.lookup("qlinear", QDIMS) == {"bm": 16}
+        assert autotune.lookup("qlinear", QDIMS) == {"bm": 8, "bn": 256}
+    assert autotune.lookup("qlinear", QDIMS) == {"bn": 128}
 
 
 def test_illegal_override_falls_through_to_defaults(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_TABLE", str(tmp_path / "absent.json"))
-    with autotune.override("qlinear", bn=7):     # 7 does not divide n=64
+    with autotune.override("qlinear", bn=7):     # 7 does not divide n
         assert autotune.lookup("qlinear", QDIMS) == {}
 
 
@@ -196,7 +200,7 @@ def test_validate_table_structural_problems(tmp_path):
         "qlinear": {
             "m=x": {"params": {}},                     # unparseable key
             "m=8": {"params": {}},                     # wrong dims
-            "e=1,m=8,k=64,n=64": {"params": {"bn": 7}},  # illegal params
+            "e=1,m=8,k=256,n=256": {"params": {"bn": 7}},  # illegal
         }}}}
     path.write_text(json.dumps(bad))
     problems = autotune.validate_table(path)
@@ -213,13 +217,13 @@ def test_validate_table_structural_problems(tmp_path):
 
 def test_override_retiles_qlinear_dispatch_bitwise():
     rng = np.random.default_rng(3)
-    k, n = 64, 64
+    k, n = 256, 256
     tw = make_ternary_weight(
         jnp.asarray(rng.standard_normal((k, n)), jnp.float32) * 0.02)
     sc = jnp.asarray(tw.scale).reshape(1, 1)
     x = jnp.asarray(rng.standard_normal((5, k)), jnp.float32)
     base = ops.qlinear_fused(x, tw.packed, sc, impl="pallas")
-    with autotune.override("qlinear", bm=8, bn=32, bkq=32, eg=1):
+    with autotune.override("qlinear", bm=8, bn=256, bkq=128, eg=1):
         tuned = ops.qlinear_fused(x, tw.packed, sc, impl="pallas")
     assert (np.asarray(base) == np.asarray(tuned)).all()
 

@@ -34,6 +34,30 @@ def test_ternary_matmul_leading_dims():
             np.asarray(ops.ternary_matmul(x, tw, impl="ref"))).all()
 
 
+@pytest.mark.parametrize("shape", [(1, 128), (3, 5, 256), (300, 3200)])
+def test_rmsnorm_kernel_matches_oracle(shape):
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32) * 3.0
+    g = jnp.asarray(rng.uniform(0.5, 1.5, (shape[-1],)), jnp.float32)
+    y_k = ops.rmsnorm(x, g, impl="pallas")
+    y_r = ops.rmsnorm(x, g, impl="ref")
+    assert y_k.shape == x.shape and y_k.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_rmsnorm_kernel_rows_independent_of_call_size():
+    """A row's result depends on that row alone: the rows of a 300-row
+    call (a whole-prompt prefill), of a 128-row call (a prefill chunk)
+    and of a 1-row call agree bit for bit."""
+    x = jnp.asarray(rng.standard_normal((300, 256)), jnp.float32)
+    g = jnp.asarray(rng.uniform(0.5, 1.5, (256,)), jnp.float32)
+    norm = jax.jit(lambda x: ops.rmsnorm(x, g, impl="pallas"))
+    whole = np.asarray(norm(x))
+    assert (np.asarray(norm(x[:128])) == whole[:128]).all()
+    for i in (0, 7, 8, 299):
+        assert (np.asarray(norm(x[i:i + 1]))[0] == whole[i]).all(), i
+
+
 @pytest.mark.parametrize("g,m,d", [(12, 1024, 128), (1, 512, 64),
                                    (40, 2048, 128)])
 def test_lop_scores_kernel(g, m, d):

@@ -111,8 +111,7 @@ PREFILL_TILINGS = [
     # kv_len below is NOT a multiple of any of these blocks
     (16, 0),
     (64, 8),
-    (32, 1),
-    (16, 4),
+    (32, 16),
 ]
 
 
@@ -141,6 +140,18 @@ def test_prefill_tiling_matrix(block, bq):
         o_b = ops.prefill_attention(qi, qsc, ki, vi, ks, vs, kv_len,
                                     impl="pallas", **kw)
     np.testing.assert_array_equal(np.asarray(o_t), np.asarray(o_b))
+
+
+@pytest.mark.parametrize("bq", [1, 4])
+def test_prefill_tiling_rejects_sub_tile_bq(bq):
+    """A query-row tile below the 8-row sublane tile is not a block Mosaic
+    takes: the screen refuses it, and an override falls back to the
+    untiled launch."""
+    dims = {"bhg": 4, "r": 16, "d": 32, "m": 64, "chunk": 8}
+    assert not autotune.valid_params("prefill", dims,
+                                     {"block": 32, "bq": bq})
+    with autotune.override("prefill", block=32, bq=bq):
+        assert autotune.lookup("prefill", dims) == {}
 
 
 def _setup(arch="bitnet-3b", **over):

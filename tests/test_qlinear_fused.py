@@ -77,7 +77,8 @@ def test_qlinear_fused_leading_dims(impl):
 @pytest.mark.parametrize("m", [1, 4, 16])
 def test_fused_qkv_segments_bitwise(m, impl):
     """One fused QKV dispatch == three per-projection dispatches, per
-    segment, bitwise — the per-column γ row carries each segment's scalar."""
+    segment, bitwise — the per-column γ row carries each segment's scalar.
+    Its 160 columns are off the 128-lane grid: one whole-dim block."""
     k, nq, nkv = 128, 96, 32
     tws = [_node(k, n) for n in (nq, nkv, nkv)]
     packed = jnp.concatenate([t.packed for t in tws], -1)
@@ -106,7 +107,7 @@ def test_fused_qkv_segments_bitwise(m, impl):
 def test_ffn_fused_bitwise_vs_unfused(gated, act, impl):
     """Whole-FFN fusion: act(x·Wg)·(x·Wu) → hidden absmax barrier → ·Wd
     in one dispatch == the three-dispatch unfused chain, bitwise."""
-    d, f, m = 128, 192, 5
+    d, f, m = 128, 256, 5
     twu, twd = _node(d, f, 0.05), _node(f, d, 0.05)
     twg = _node(d, f, 0.05) if gated else None
 
@@ -197,6 +198,91 @@ def test_grouped_expert_ffn_bitwise(impl):
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
+def _gated_unfused(twg, twu, twd, x):
+    h = apply_act(_unfused(twg, x), "silu") * _unfused(twu, x)
+    return _unfused(twd, h)
+
+
+def _gu_node(twg, twu):
+    f = twu.shape[1]
+    return (jnp.concatenate([twg.packed, twu.packed], -1),
+            jnp.concatenate(
+                [jnp.broadcast_to(jnp.asarray(t.scale).reshape(1, 1), (1, f))
+                 for t in (twg, twu)], -1))
+
+
+@pytest.mark.parametrize("impl", ARMS)
+def test_ffn_fused_multi_row_tile_bitwise(impl):
+    """More rows than one 128-row tile (a whole-prompt prefill): every
+    row tile lands in its own output rows, bitwise the unfused chain."""
+    d, f, m = 128, 256, 200
+    twg, twu, twd = _node(d, f, 0.05), _node(d, f, 0.05), _node(f, d, 0.05)
+    gu_packed, gu_scale = _gu_node(twg, twu)
+    x = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
+    want = jax.jit(lambda x: _gated_unfused(twg, twu, twd, x))(x)
+    got = jax.jit(lambda x: ops.ffn_fused(
+        x, gu_packed, gu_scale, twd.packed,
+        jnp.asarray(twd.scale).reshape(1, 1), gated=True, act="silu",
+        impl=impl))(x)
+    assert np.isfinite(np.asarray(got)).all()
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("impl", ARMS)
+def test_ffn_hidden_padding_bitwise(impl):
+    """A hidden width off the 128-lane grid (f = 200) is padded with zero
+    codes when quantize_params fuses the FFN. Zero columns leave the
+    hidden vector and its absmax unchanged, so the padded node serves
+    bitwise the unpadded unfused chain."""
+    from types import SimpleNamespace
+
+    from repro.serving.quantize import quantize_params
+
+    d, f, m = 128, 200, 5
+    ws = {nm: jnp.asarray(rng.standard_normal(shape), jnp.float32) * 0.05
+          for nm, shape in (("w_gate", (d, f)), ("w_up", (d, f)),
+                            ("w_down", (f, d)))}
+    node = quantize_params(SimpleNamespace(quant="ternary"),
+                           {"ffn": {nm: {"w": w} for nm, w in ws.items()}}
+                           )["ffn"]
+    assert node["gu_packed"].shape == (d // 4, 2 * 256)
+    assert node["down_packed"].shape == (256 // 4, d)
+    twg, twu, twd = (make_ternary_weight(ws[nm])
+                     for nm in ("w_gate", "w_up", "w_down"))
+    gu = np.asarray(node["gu_packed"])
+    assert (gu[:, :f] == np.asarray(twg.packed)).all()
+    assert (gu[:, 256:256 + f] == np.asarray(twu.packed)).all()
+    assert not gu[:, f:256].any() and not gu[:, 256 + f:].any()
+
+    x = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
+    want = jax.jit(lambda x: _gated_unfused(twg, twu, twd, x))(x)
+    got = jax.jit(lambda x: ops.ffn_fused(
+        x, node["gu_packed"], node["gu_scale"], node["down_packed"],
+        node["down_scale"], gated=True, act="silu", impl=impl))(x)
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("n,col_bytes,block", [
+    (100, 800, 100), (128, 800, 128), (8704, 800, 128),
+    (160, 32, 160),                 # fused QKV off the 128 grid: whole dim
+    (384, None, 128),               # gated hidden on the grid
+])
+def test_pick_block_legal_dims(n, col_bytes, block):
+    assert autotune.pick_block(n, col_bytes) == block
+
+
+@pytest.mark.parametrize("n,col_bytes", [
+    (8640, 800),            # bitnet-3b's FFN hidden: whole dim > VMEM
+    (192, None),            # gated hidden: f is not its array's whole dim
+])
+def test_pick_block_refuses_dims_without_legal_block(n, col_bytes):
+    """Mosaic takes a lane block only as a multiple of 128 or the whole
+    dim: a dim off the 128 grid whose whole-dim block does not fit VMEM,
+    or is not the array's whole dim, has no legal block."""
+    with pytest.raises(ValueError, match="no legal column block"):
+        autotune.pick_block(n, col_bytes)
+
+
 # ---------------------------------------------------------------------------
 # Autotune tiling matrix (DESIGN.md §Autotuning): every swept block shape
 # is a pure tiling choice — bitwise the same oracle, under both arms
@@ -206,10 +292,10 @@ QLINEAR_TILINGS = [
     # (bm, bn, bkq, eg): single-pass barrier at small tiles; the two-pass
     # k-tiled barrier; k-tiling + expert grouping; whole-e group with
     # bkq == k (one k-tile, degenerate two-pass)
-    (8, 32, 0, 1),
-    (8, 96, 16, 1),
-    (16, 96, 32, 2),
-    (8, 48, 64, 4),
+    (8, 128, 0, 1),
+    (8, 384, 128, 1),
+    (16, 384, 128, 2),
+    (8, 128, 256, 4),
 ]
 
 
@@ -219,7 +305,7 @@ def test_qlinear_tiling_matrix_bitwise(bm, bn, bkq, eg, impl):
     """Every swept (bm, bn, bkq, eg) — including the two-pass k-tiled
     absmax barrier — dispatches bitwise-equal to the per-expert unfused
     oracle under an autotune.override, both arms."""
-    e, c, k, n = 4, 6, 64, 96
+    e, c, k, n = 4, 6, 256, 384
     params = {"bm": bm, "bn": bn, "bkq": bkq, "eg": eg}
     assert autotune.valid_params(
         "qlinear", {"e": e, "m": c, "k": k, "n": n}, params)
@@ -244,9 +330,9 @@ def test_qlinear_tiling_matrix_bitwise(bm, bn, bkq, eg, impl):
 FFN_TILINGS = [
     # (bm, bf, bn, bkq): default-ish; fine hidden tiles + k-tiled
     # barrier; coarse everything with bkq == k
-    (8, 64, 32, 0),
-    (8, 192, 64, 16),
-    (16, 96, 16, 64),
+    (8, 128, 128, 0),
+    (8, 256, 128, 128),
+    (16, 128, 256, 256),
 ]
 
 
@@ -255,7 +341,7 @@ FFN_TILINGS = [
 def test_ffn_tiling_matrix_bitwise(bm, bf, bn, bkq, impl):
     """Swept FFN tilings (incl. the k-tiled input barrier) == the
     three-dispatch unfused chain, bitwise, both arms."""
-    d, f, m = 64, 192, 5
+    d, f, m = 256, 256, 5
     params = {"bm": bm, "bf": bf, "bn": bn, "bkq": bkq}
     assert autotune.valid_params(
         "ffn", {"e": 1, "m": m, "k": d, "f": f, "n": d}, params)

@@ -91,6 +91,26 @@ def test_greedy_generation_deterministic():
     assert (a >= 0).all() and (a < cfg.vocab_padded).all()
 
 
+@pytest.mark.parametrize("n", [4, 11])
+def test_logits_rows_independent_of_batch(n):
+    """A row's logits do not depend on how many rows share the head call:
+    a pool lane's greedy token must equal the batch-1 lockstep path's,
+    bitwise. The head runs on fixed 8-row tiles; an untiled f32 matmul
+    changes algorithm with the row count (on the CPU and on the TPU)."""
+    from repro.serving.engine import _logits
+
+    cfg = _reduced("bitnet-3b")
+    params, _ = init_params(cfg, jax.random.PRNGKey(0))
+    qp = quantize_params(cfg, params)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (n, cfg.d_model)), jnp.float32)
+    logits = jax.jit(lambda x: _logits(cfg, qp, x))
+    batched = np.asarray(logits(x))
+    assert batched.shape == (n, cfg.vocab_padded)
+    for i in range(n):
+        assert (np.asarray(logits(x[i:i + 1]))[0] == batched[i]).all(), i
+
+
 def test_swa_window_limits_decode_attention():
     """Mixtral SWA: tokens beyond the (depth-stacked) receptive field must
     not affect decode. With 1 layer + window W, the decode step at position
