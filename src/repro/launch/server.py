@@ -15,6 +15,9 @@ writes into (DESIGN.md §Serving-metrics) — the same metric names
 diffable dashboards. ``--shape-log`` arms the log-and-sweep sidecar:
 every distinct kernel dispatch shape the engine traces lands in a JSON
 file that ``python -m repro.kernels.autotune --from-log`` sweeps later.
+``--profile-port N`` starts JAX's profiler server on port N, so a
+profiler client can capture a trace of the live server with the serve
+cycle's spans in it (``serving/metrics.py`` ``SPANS``).
 """
 
 from __future__ import annotations
@@ -90,12 +93,17 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--shape-log", default=None,
                     help="JSON sidecar recording kernel dispatch shapes "
                          "for `repro.kernels.autotune --from-log`")
+    ap.add_argument("--profile-port", type=int, default=None,
+                    help="start the JAX profiler server on this port, for "
+                         "capturing traces of the live server")
     return ap
 
 
 def main() -> None:
     args = build_parser().parse_args()
     compile_cache.enable()
+    if args.profile_port is not None:
+        jax.profiler.start_server(args.profile_port)
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

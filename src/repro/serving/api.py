@@ -54,7 +54,8 @@ import numpy as np
 from repro.serving import cache as _cache
 from repro.serving import faults as _faults
 from repro.serving.engine import (draft_step, guard_logits, prefill,
-                                  prefill_chunk, serve_step)
+                                  prefill_chunk as chunk_forward, serve_step)
+from repro.serving.metrics import span
 from repro.serving.sampling import sample_with_seed
 
 # ---------------------------------------------------------------------------
@@ -543,17 +544,19 @@ class PooledEngine:
         if fn is None:
             cfg = self.cfg
 
-            def run(qp, pool_, slot_, toks, start_, seq_end_, activate_,
-                    kw_):
+            # the function's name names the compiled program
+            # (``jit_prefill_chunk``) in a profiler trace
+            def prefill_chunk(qp, pool_, slot_, toks, start_, seq_end_,
+                              activate_, kw_):
                 lane = _cache.extract_slot(pool_, slot_)
-                logits, lane = prefill_chunk(cfg, qp, toks, lane,
+                logits, lane = chunk_forward(cfg, qp, toks, lane,
                                              start=start_, seq_end=seq_end_,
                                              **kw_)
                 pool_ = _cache.insert_slot(pool_, slot_, lane,
                                            active=activate_)
                 return logits, pool_
 
-            fn = jax.jit(run, donate_argnums=(1,))
+            fn = jax.jit(prefill_chunk, donate_argnums=(1,))
             self._fns[key] = fn
             self.prefill_compiles += 1
         jnp = self._jnp
@@ -605,17 +608,19 @@ class PooledEngine:
         fadd = _faults.decode_fault_add(n)
         fadd = jnp.asarray(np.zeros((n,), np.float32) if fadd is None
                            else fadd)
-        if np.all(np.asarray(temperature) <= 0.0):
-            toks, ok, pool = self._decode_greedy_fn(self.qp, pool,
-                                                    jnp.asarray(tokens),
-                                                    fadd)
-        else:
-            toks, ok, pool = self._decode_fn(
-                self.qp, pool, jnp.asarray(tokens),
-                jnp.asarray(temperature), jnp.asarray(top_k),
-                jnp.asarray(top_p), fadd)
-        self.last_ok = np.asarray(ok)
-        return np.asarray(toks), pool
+        with span("repro.decode.dispatch", lanes=n):
+            if np.all(np.asarray(temperature) <= 0.0):
+                toks, ok, pool = self._decode_greedy_fn(
+                    self.qp, pool, jnp.asarray(tokens), fadd)
+            else:
+                toks, ok, pool = self._decode_fn(
+                    self.qp, pool, jnp.asarray(tokens),
+                    jnp.asarray(temperature), jnp.asarray(top_k),
+                    jnp.asarray(top_p), fadd)
+        with span("repro.decode.readback"):
+            self.last_ok = np.asarray(ok)
+            toks = np.asarray(toks)
+        return toks, pool
 
     def retry_step(self, pool, slot, tokens, temperature, top_k, top_p):
         """Recovery twin of :meth:`decode_step` for ONE quarantined lane
@@ -681,10 +686,10 @@ class PooledEngine:
         if fn is None:
             cfg, use_lop = self.cfg, self.use_lop
 
-            def run(qp, pool_, slot_, toks, start_):
+            def verify_chunk(qp, pool_, slot_, toks, start_):
                 lane = _cache.extract_slot(pool_, slot_)
                 width = toks.shape[1]
-                logits, lane = prefill_chunk(
+                logits, lane = chunk_forward(
                     cfg, qp, toks, lane, start=start_,
                     seq_end=start_ + width, all_logits=True)
                 pool_ = _cache.insert_slot(pool_, slot_, lane, active=True)
@@ -693,7 +698,7 @@ class PooledEngine:
                     pool_["sample_step"].at[slot_].add(1)
                 return logits, pool_
 
-            fn = jax.jit(run, donate_argnums=(1,))
+            fn = jax.jit(verify_chunk, donate_argnums=(1,))
             self._fns[key] = fn
             self.prefill_compiles += 1
         jnp = self._jnp

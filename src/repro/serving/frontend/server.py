@@ -91,11 +91,13 @@ class SchedulerPump(threading.Thread):
     def run(self) -> None:
         try:
             while not self._stopping.is_set():
-                moved = self._drain_inbox(block=not self.sched.has_work())
-                self.sched.admit()
-                if self.sched.has_work():
-                    self.sched.step()
-                self._dispatch_done()
+                with _metrics.span("repro.cycle"):
+                    moved = self._drain_inbox(
+                        block=not self.sched.has_work())
+                    self.sched.admit()
+                    if self.sched.has_work():
+                        self.sched.step()
+                    self._dispatch_done()
                 if not moved and not self.sched.has_work() \
                         and self._stopping.is_set():
                     break
@@ -117,8 +119,11 @@ class SchedulerPump(threading.Thread):
         timeout = self.idle_poll_s if block else None
         while True:
             try:
-                item = (self.inbox.get(timeout=timeout) if block
-                        else self.inbox.get_nowait())
+                if block:
+                    with _metrics.span("repro.inbox_wait"):
+                        item = self.inbox.get(timeout=timeout)
+                else:
+                    item = self.inbox.get_nowait()
             except queue.Empty:
                 return moved
             block = False

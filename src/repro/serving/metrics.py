@@ -1,7 +1,7 @@
 """Observability core for the serving stack (DESIGN.md §Serving-frontend).
 
 A process-wide registry of counters / gauges / fixed-bucket histograms
-with Prometheus text exposition, plus the two helpers the rest of the
+with Prometheus text exposition, plus the helpers the rest of the
 repo shares:
 
 - :func:`percentile` / :func:`summarize` — THE percentile computation.
@@ -11,17 +11,21 @@ repo shares:
 - :class:`StageTimer` — per-request span recorder for the
   queue → prefill-chunks → decode (→ spec draft/verify) lifecycle the
   scheduler threads through (one timer per request, ``clock``-agnostic
-  so virtual-clock tests stay deterministic).
+  so virtual-clock tests stay deterministic);
+- :func:`span` — the serve cycle's profiler spans, named in
+  :data:`SPANS`, on the same clock as the device's ops in a trace.
 
 Design constraints, in order:
 
-- stdlib + numpy only (the HTTP frontend must not grow dependencies);
+- stdlib + numpy at import (the HTTP frontend must not grow
+  dependencies; :func:`span` imports ``jax.profiler`` when called);
 - instruments are *mergeable*: fixed bucket bounds and monotone
   counters mean two registries (e.g. per-worker, the future
   disaggregated pool) combine by addition (:meth:`MetricsRegistry.merge`);
 - one place owns the metric NAMES (:func:`scheduler_instruments`,
-  :func:`http_instruments`), so the synthetic driver and the HTTP
-  server export identical series and dashboards don't fork.
+  :func:`http_instruments`) and the span names (:data:`SPANS`), so
+  ``launch/serve.py`` and the HTTP server export identical series and
+  dashboards don't fork.
 
 Thread-safety: every mutation takes a per-registry lock. The scheduler
 pump thread and the asyncio loop both write; ``/metrics`` renders from
@@ -392,6 +396,43 @@ class StageTimer:
 
 
 # ---------------------------------------------------------------------------
+# Profiler spans of the serve cycle
+# ---------------------------------------------------------------------------
+
+#: every host span the serving path writes into a profiler trace, one
+#: per phase of the serve cycle (the span table in DESIGN.md
+#: §Serving-metrics mirrors this list). Request-level spans carry the
+#: request's ``rid``, so one request's spans share an identifier.
+SPANS = (
+    "repro.cycle",              # one admit/step iteration of a serve loop
+    "repro.inbox_wait",         # an idle pump blocked on its inbox
+    "repro.admit",              # Scheduler.admit (admitted)
+    "repro.prefill_chunk",      # one chunk dispatch (rid, chunk, tokens)
+    "repro.intern_prefix",      # a finished prompt into the store (rid)
+    "repro.first_token",        # sample_first readback, first emit (rid)
+    "repro.decode.dispatch",    # the jitted decode step's enqueue (lanes)
+    "repro.decode.readback",    # waiting for its tokens on the host
+    "repro.emit",               # appending/streaming its tokens (tokens)
+    "repro.finish",             # evict and publish a lane (rid)
+    "repro.spec_draft",         # one batched draft launch
+    "repro.spec_verify",        # one verify launch
+)
+
+
+def span(name: str, **args):
+    """A host span on the profiler's clock: a context manager recording
+    ``name`` (and ``args`` as event stats) into a running
+    ``jax.profiler`` trace, and costing about a microsecond with no
+    trace running. ``name`` must be one of :data:`SPANS`."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}: span names live in "
+                         "metrics.SPANS")
+    # imported here: this module stays stdlib + numpy at import
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **args)
+
+
+# ---------------------------------------------------------------------------
 # The shared metric names (driver + HTTP server export these identically)
 # ---------------------------------------------------------------------------
 
@@ -447,18 +488,6 @@ def scheduler_instruments(registry: MetricsRegistry) -> _Namespace:
             "repro_request_itl_seconds", "inter-token decode gaps"),
         e2e=registry.histogram(
             "repro_request_e2e_seconds", "arrival to retirement"),
-        prefill_chunk=registry.histogram(
-            "repro_prefill_chunk_seconds",
-            "one engine.prefill_chunk launch"),
-        decode_step=registry.histogram(
-            "repro_decode_step_seconds",
-            "one batched engine.decode_step launch"),
-        spec_draft=registry.histogram(
-            "repro_spec_draft_seconds",
-            "one batched engine.draft launch"),
-        spec_verify=registry.histogram(
-            "repro_spec_verify_seconds",
-            "one engine.verify_chunk launch"),
     )
 
 

@@ -81,6 +81,7 @@ from repro.serving.api import (GREEDY, ExistingPrefix, FinishedRequest,
                                StepResult)
 from repro.serving.cache import PrefixStore, pool_capacity
 from repro.serving.faults import PrefixLookupError
+from repro.serving.metrics import span
 
 # Back-compat names — the typed API in repro.serving.api is the source of
 # truth; the old scheduler-local dataclasses are these aliases now.
@@ -387,6 +388,12 @@ class Scheduler:
         immediately. Cancelled queue entries retire without touching a
         lane.
         """
+        with span("repro.admit") as sp:
+            n = self._admit()
+            sp.set_metadata(admitted=n)
+        return n
+
+    def _admit(self) -> int:
         n = 0
         clones: dict = {}          # prefix node key -> (node, [slots])
         while self.queue and self._free:
@@ -464,29 +471,30 @@ class Scheduler:
         """Prefill finished: seed the lane with the prompt's sampled first
         token (index 0 of the request's key schedule) and write the lane's
         PRNG state (seed, next step index) into the pool."""
-        sp = req.sampling or GREEDY
-        first = self.engine.sample_first(logits, sp)
-        self.pool = self.engine.set_sampling_state(self.pool, slot,
-                                                   sp.seed, 1)
-        now = self.clock()
-        timer = self._timers.get(req.rid)
-        if timer is not None:
-            timer.to("decode")
-        if req.arrival is not None:
-            self._m.ttft.observe(now - req.arrival)
-        lane = _Lane(req=req, tokens=[first],
-                     remaining=req.max_new_tokens - 1,
-                     t_admit=t_admit, t_first=now, token_times=[now],
-                     cached_len=cached_len)
-        self.lanes[slot] = lane
-        self._m.active_lanes.set(self.n_active)
-        self._next_tok[slot, 0] = first
-        reason = self._token_reason(lane, first)   # evaluated exactly once
-        self._emit(lane, first, 0, reason)
-        if reason is not None:
-            result = self._finish(slot, reason)
-            if done is not None:
-                done.append(result)
+        with span("repro.first_token", rid=req.rid):
+            sp = req.sampling or GREEDY
+            first = self.engine.sample_first(logits, sp)
+            self.pool = self.engine.set_sampling_state(self.pool, slot,
+                                                       sp.seed, 1)
+            now = self.clock()
+            timer = self._timers.get(req.rid)
+            if timer is not None:
+                timer.to("decode")
+            if req.arrival is not None:
+                self._m.ttft.observe(now - req.arrival)
+            lane = _Lane(req=req, tokens=[first],
+                         remaining=req.max_new_tokens - 1,
+                         t_admit=t_admit, t_first=now, token_times=[now],
+                         cached_len=cached_len)
+            self.lanes[slot] = lane
+            self._m.active_lanes.set(self.n_active)
+            self._next_tok[slot, 0] = first
+            reason = self._token_reason(lane, first)   # evaluated exactly once
+            self._emit(lane, first, 0, reason)
+            if reason is not None:
+                result = self._finish(slot, reason)
+                if done is not None:
+                    done.append(result)
 
     def _step_prefill(self, done: list) -> bool:
         """Advance ONE chunk of the oldest mid-prefill lane."""
@@ -498,11 +506,11 @@ class Scheduler:
         kw = {}
         if k == 0 and self.engine.prefix_len(pf.req):
             kw["patches"] = jnp.asarray(pf.req.patches)[None]
-        t0 = self.clock()
-        logits, self.pool = self.engine.prefill_chunk(
-            self.pool, pf.slot, pf.chunks[k], pf.starts[k], pf.seq_ends[k],
-            final, kw)
-        self._m.prefill_chunk.observe(self.clock() - t0)
+        with span("repro.prefill_chunk", rid=pf.req.rid, chunk=k,
+                  tokens=pf.seq_ends[k] - pf.starts[k]):
+            logits, self.pool = self.engine.prefill_chunk(
+                self.pool, pf.slot, pf.chunks[k], pf.starts[k],
+                pf.seq_ends[k], final, kw)
         pf.next_chunk += 1
         if final:
             self._prefilling.popleft()
@@ -525,10 +533,12 @@ class Scheduler:
         if store is None or self.engine.prefix_len(pf.req):
             return
         n = (len(pf.req.prompt) // store.block) * store.block
-        if not n or not store.missing(pf.req.prompt[:n]):
+        if not n:
             return
-        lane = self.engine.extract(self.pool, pf.slot)
-        store.insert(pf.req.prompt[:n], lane)
+        with span("repro.intern_prefix", rid=pf.req.rid):
+            if store.missing(pf.req.prompt[:n]):
+                lane = self.engine.extract(self.pool, pf.slot)
+                store.insert(pf.req.prompt[:n], lane)
 
     # ---------------- decode / evict ----------------
 
@@ -670,10 +680,9 @@ class Scheduler:
         drafts: dict[int, list[int]] = {s: [] for s in active}
         cur = self._next_tok.copy()
         for _ in range(g):
-            t0 = self.clock()
-            toks, self.pool = self.engine.draft(self.pool, cur, temps,
-                                                tks, tps)
-            self._m.spec_draft.observe(self.clock() - t0)
+            with span("repro.spec_draft"):
+                toks, self.pool = self.engine.draft(self.pool, cur, temps,
+                                                    tks, tps)
             self.draft_launches += 1
             for s in active:
                 d = int(toks[s])
@@ -692,10 +701,9 @@ class Scheduler:
             block = np.concatenate(
                 [self._next_tok[slot], np.asarray(drafts[slot], np.int32)]
             )[None, :]
-            t0 = self.clock()
-            logits, self.pool = self.engine.verify_chunk(
-                self.pool, slot, block, start)
-            self._m.spec_verify.observe(self.clock() - t0)
+            with span("repro.spec_verify"):
+                logits, self.pool = self.engine.verify_chunk(
+                    self.pool, slot, block, start)
             self.spec_verify_launches += 1
             if not bool(np.isfinite(np.asarray(logits)).all()):
                 # poisoned verify logits: rewind the whole round for this
@@ -833,38 +841,38 @@ class Scheduler:
             if g >= 1:
                 self._spec_round(g, temps, tks, tps, done)
                 return done
-        t0 = self.clock()
         toks, self.pool = self.engine.decode_step(
             self.pool, self._next_tok, temps, tks, tps)
-        self._m.decode_step.observe(self.clock() - t0)
         self.decode_launches += 1
         # per-lane logit-finiteness guard published by the engine (None:
         # an engine without the guard — every lane treated healthy)
         ok = getattr(self.engine, "last_ok", None)
-        for slot, lane in enumerate(self.lanes):
-            if lane is None:
-                continue
-            if ok is not None and not bool(ok[slot]):
-                self._recover_lane(slot, temps, tks, tps, done)
-                continue
-            self._append_token(slot, int(toks[slot]), done)
+        with span("repro.emit", tokens=self.n_active):
+            for slot, lane in enumerate(self.lanes):
+                if lane is None:
+                    continue
+                if ok is not None and not bool(ok[slot]):
+                    self._recover_lane(slot, temps, tks, tps, done)
+                    continue
+                self._append_token(slot, int(toks[slot]), done)
         return done
 
     def _finish(self, slot: int, reason: str) -> FinishedRequest:
         lane = self.lanes[slot]
-        res = FinishedRequest(
-            rid=lane.req.rid, prompt_len=len(lane.req.prompt),
-            tokens=lane.tokens, finish_reason=reason,
-            t_arrival=lane.req.arrival, t_admit=lane.t_admit,
-            t_first=lane.t_first, t_done=self.clock(),
-            token_times=lane.token_times, cached_len=lane.cached_len)
-        self.pool = self.engine.evict(self.pool, slot)
-        self.lanes[slot] = None
-        self._free.append(slot)
-        self._next_tok[slot, 0] = 0
-        self.results.append(res)
-        self._m.active_lanes.set(self.n_active)
-        self._publish_finish(res, reason)
+        with span("repro.finish", rid=lane.req.rid):
+            res = FinishedRequest(
+                rid=lane.req.rid, prompt_len=len(lane.req.prompt),
+                tokens=lane.tokens, finish_reason=reason,
+                t_arrival=lane.req.arrival, t_admit=lane.t_admit,
+                t_first=lane.t_first, t_done=self.clock(),
+                token_times=lane.token_times, cached_len=lane.cached_len)
+            self.pool = self.engine.evict(self.pool, slot)
+            self.lanes[slot] = None
+            self._free.append(slot)
+            self._next_tok[slot, 0] = 0
+            self.results.append(res)
+            self._m.active_lanes.set(self.n_active)
+            self._publish_finish(res, reason)
         return res
 
     def _record_abort(self, req: GenerateRequest, t_admit: float = 0.0,
@@ -967,8 +975,9 @@ class Scheduler:
     def run_to_completion(self) -> list[FinishedRequest]:
         """Drain queue + lanes (all requests already submitted)."""
         while self.has_work():
-            self.admit()
-            self.step()
+            with span("repro.cycle"):
+                self.admit()
+                self.step()
         return self.results
 
 

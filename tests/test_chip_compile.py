@@ -117,6 +117,30 @@ def test_decode_attention_compiles(compile_for, use_lop, b, m):
     assert "tpu_custom_call" in hlo
 
 
+def test_lop_launches_keep_their_instruction_names(compile_for):
+    """Both LOP launches still compile to instructions named
+    ``fused_decode_attention.N`` (what a profiler trace calls them), and
+    the instruction text (what a TPU trace labels the op with) carries
+    each launch's phase, ``lop_screen`` or ``lop_exact``."""
+    import re
+    b, m, hkv = B, M, CFG.n_kv_heads
+    hlo = compile_for(
+        lambda qi, qs, k, v, ks, vs, f, nl: ops.decode_attention(
+            qi, qs, k, v, ks, vs, f, nl, block=CFG.lop_block,
+            k_keep=k_keep_blocks(CFG, m), use_lop=True, impl="pallas"),
+        ((b, H, DH), jnp.int8), ((b, H, 1), jnp.float32),
+        ((b, hkv, m, DH), jnp.int8), ((b, hkv, m, DH), jnp.int8),
+        ((b, hkv, m), jnp.float32), ((b, hkv, m), jnp.float32),
+        ((b, hkv, m, DH // 2), jnp.uint8), ((b,), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    phases = re.findall(
+        r'%fused_decode_attention(?:\.\d+)? = [^\n]*custom-call\([^\n]*'
+        r'kernel_metadata=\{\s*"scope":"(lop_\w+)"', hlo)
+    assert sorted(phases) == ["lop_exact", "lop_screen"]
+    # the candidate selection's fusions carry the screen's mark too
+    assert 'scope="lop_screen"' in hlo
+
+
 def test_prefill_attention_compiles(compile_for):
     c, hkv = CFG.lop_block, CFG.n_kv_heads
     hlo = compile_for(

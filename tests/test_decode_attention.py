@@ -161,6 +161,27 @@ def test_decode_n_slots_matrix_bitwise(n_slots, shared):
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("use_lop", [True, False], ids=["lop", "dense"])
+def test_lop_phases_carry_their_scopes(use_lop):
+    """The screen phase (screen launch + candidate selection) and the
+    exact launch lower under the scopes ``lop_screen`` / ``lop_exact``,
+    which a profiler trace shows in each device op's name; the dense
+    launch has neither."""
+    from repro.kernels.decode_attention import fused_decode_attention
+    bh, g, d, m = 4, 2, 32, 128
+    sds = jax.ShapeDtypeStruct
+    args = (sds((bh, g, d), jnp.int8), sds((bh, g, 1), jnp.float32),
+            sds((bh, m, d), jnp.int8), sds((bh, m, d), jnp.int8),
+            sds((bh, m, 1), jnp.float32), sds((bh, m, 1), jnp.float32),
+            sds((bh, m, d // 2), jnp.uint8), sds((bh // 2,), jnp.int32),
+            sds((1,), jnp.int32))
+    text = fused_decode_attention.lower(
+        *args, hkv=2, block=32, k_keep=2, window=0, softmax_scale=0.1,
+        use_lop=use_lop, interpret=True).as_text(debug_info=True)
+    for scope in ("lop_screen/", "lop_exact/"):
+        assert (scope in text) == use_lop, scope
+
+
 @pytest.mark.parametrize("n_slots", [3, 4])
 def test_decode_n_slots_beyond_double_buffering_rejected(n_slots):
     """Mosaic pipelines at most two buffers per input: deeper candidate
