@@ -66,7 +66,11 @@ def lop_features(x: jax.Array) -> jax.Array:
 
 
 def features_to_pot(feat: jax.Array) -> jax.Array:
-    """Decode nibbles back to pot() int8 values."""
+    """Decode nibbles back to pot() int8 values.
+
+    Also the in-kernel decode of the LOP screen
+    (``kernels.lop_scores.nibble_planes_to_pot``), which passes int32
+    nibbles: Mosaic cannot shift int8 vectors."""
     lo = (feat & 0x7).astype(jnp.int32)
     sgn = ((feat >> 3) & 0x1).astype(jnp.int32)
     mag = jnp.where(lo == LO_ZERO, 0, jnp.left_shift(1, jnp.minimum(lo, 6)))

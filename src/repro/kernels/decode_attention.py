@@ -9,11 +9,16 @@ accelerators (PAPERS.md). Per (b, kv-head) lane the LOP path runs three
 phases (DESIGN.md §Fused-decode-kernel):
 
   screen   launch 1, steps ``j < NB``: the packed 4-bit (sgn‖LO)
-           feature cache streams block by block; nibbles expand to
-           pot-int8 in VMEM, one int8 MXU dot yields surrogate scores,
-           invalid tokens mask to INT32_MIN, and the per-block maxima
-           land in VMEM scratch (fully-masked blocks score −inf so they
-           can never be picked).
+           feature cache streams block by block; the two nibble planes
+           expand to pot-int8 in VMEM separately, and two int8 MXU dots
+           against pot(q)'s even and odd columns (split once, outside
+           the kernel) sum to the surrogate scores; invalid tokens mask
+           to INT32_MIN, and the per-block maxima land in VMEM scratch
+           (fully-masked blocks score −inf so they can never be picked).
+           The planes are never interleaved back into [block, d]:
+           Mosaic lowers that lane interleave to thousands of relayout
+           ops (sublane shuffles, gathers) a block, 17× the ops of the
+           whole two-dot step.
   select   launch 1, step ``j == NB − 1``: the comparison-free
            bucketized top-K (the ASIC's histogram + prefix-scan
            selector, mirroring :func:`repro.core.lop.comparison_free_topk`
@@ -80,7 +85,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core.lop import DEFAULT_N_BUCKETS, comparison_free_rank, pot
-from repro.kernels.lop_scores import _nibbles_to_pot
+from repro.kernels.lop_scores import plane_scores
 
 NEG_INF = -1e30
 INT32_MIN = jnp.iinfo(jnp.int32).min
@@ -121,8 +126,8 @@ def _flush(o_ref, m_out, l_out, m_ref, l_ref, acc_ref, return_stats):
 # LOP launch 1: screen + select
 # ---------------------------------------------------------------------------
 
-def _lop_screen_kernel(nl_ref, po_ref, qi_ref, feat_ref, rank_ref, blk_ref,
-                       *, nb, hkv, block, k_keep, window, n_buckets,
+def _lop_screen_kernel(nl_ref, po_ref, qe_ref, qo_ref, feat_ref, rank_ref,
+                       blk_ref, *, nb, hkv, block, k_keep, window, n_buckets,
                        shared_select):
     """Grid (b·hkv, NB): screen every feature block, then rank them."""
     bh = pl.program_id(0)
@@ -135,11 +140,7 @@ def _lop_screen_kernel(nl_ref, po_ref, qi_ref, feat_ref, rank_ref, blk_ref,
         blk_ref[...] = jnp.full_like(blk_ref, -jnp.inf)
 
     # ---- screen: surrogate block scores from the packed feature cache ----
-    qp = pot(qi_ref[0])                                  # [G, d] int8
-    kp = _nibbles_to_pot(feat_ref[0], qp.shape[-1])      # [block, d] int8
-    s = jax.lax.dot_general(
-        qp, kp, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)                # [G, block]
+    s = plane_scores(qe_ref[0], qo_ref[0], feat_ref[0])  # [G, block] int32
     tpos = po + j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     tvalid = tpos < nl
     if window:
@@ -391,6 +392,8 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
     # scope around it: each launch names itself ``fused_decode_attention``
     # so its instruction keeps the name the dense launch has.
     with _phase("lop_screen") as mark:
+        qp = pot(qi)
+        half = pl.BlockSpec((1, g, d // 2), lane)
         rank = pl.pallas_call(
             functools.partial(_lop_screen_kernel, nb=nb, hkv=hkv,
                               block=block, k_keep=k_keep, window=window,
@@ -400,7 +403,7 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
                 num_scalar_prefetch=2,
                 grid=(bhg, nb),
                 in_specs=[
-                    pl.BlockSpec((1, g, d), lane),
+                    half, half,
                     pl.BlockSpec((1, block, d // 2),
                                  lambda bh, j, *_: (bh, j, 0)),
                 ],
@@ -410,7 +413,7 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
             out_shape=jax.ShapeDtypeStruct((bhg, g_sel, nbp), jnp.int32),
             interpret=interpret,
             name="fused_decode_attention", metadata=mark,
-        )(new_len, pos_off, qi, feat)
+        )(new_len, pos_off, qp[..., 0::2], qp[..., 1::2], feat)
         ids, gate = _candidates(rank, k_keep, nb)
 
     # the pipeline fetches candidate c's block by the prefetched id; with

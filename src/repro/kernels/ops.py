@@ -289,8 +289,10 @@ def lop_screen(q: jax.Array, feat_packed: jax.Array, *,
     bm = min(_lop.DEFAULT_BM, m)
     qpp, g0 = _pad_to(qp, bq, 0)
     assert m % bm == 0, (m, bm)
-    out = _lop.lop_scores_kernel(qpp, feat_packed, bq=bq, bm=bm,
-                                 interpret=_interpret())
+    # the kernel scores q's even and odd columns against the low and high
+    # nibble planes (lop_scores.plane_scores)
+    out = _lop.lop_scores_kernel(qpp[:, 0::2], qpp[:, 1::2], feat_packed,
+                                 bq=bq, bm=bm, interpret=_interpret())
     return out[:g0].reshape(*lead, m)
 
 

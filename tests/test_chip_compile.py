@@ -6,7 +6,8 @@ shifts, lane slices off a 128 multiple, blocks off the (8, 128) tile.
 These tests hand the kernels to Mosaic for a described, unattached v5e
 chip — nothing runs, so they check compilation only — at the widths
 ``chip_smoke.py`` serves: d 3200, 32 heads of dim 100, FFN hidden 8640
-(padded to 8704), 2176- and 4096-token caches in 128-token LOP blocks.
+(padded to 8704), 2176- and 4096-token caches in 128-token LOP blocks;
+and the LOP decode at stablelm-1.6b's head dim 64.
 
 The topology is described inside a module fixture, never at import:
 every test worker imports this file, and loading the TPU library is
@@ -115,6 +116,24 @@ def test_decode_attention_compiles(compile_for, use_lop, b, m):
         ((b, hkv, m), jnp.float32), ((b, hkv, m), jnp.float32),
         ((b, hkv, m, DH // 2), jnp.uint8), ((b,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_lop_decode_compiles_at_stablelm_width(compile_for):
+    """The LOP decode at stablelm-1.6b's head dim 64 (32 kv heads: one
+    slot is 32 lanes, 4096-token cache), beside bitnet-3b's 100 above:
+    the screen's two nibble-plane dots compile at both served widths."""
+    cfg = get_config("stablelm-1.6b")
+    h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    b, m = 1, round_up(4096 + 1, cfg.lop_block)
+    hlo = compile_for(
+        lambda qi, qs, k, v, ks, vs, f, nl: ops.decode_attention(
+            qi, qs, k, v, ks, vs, f, nl, block=cfg.lop_block,
+            k_keep=k_keep_blocks(cfg, m), use_lop=True, impl="pallas"),
+        ((b, h, dh), jnp.int8), ((b, h, 1), jnp.float32),
+        ((b, hkv, m, dh), jnp.int8), ((b, hkv, m, dh), jnp.int8),
+        ((b, hkv, m), jnp.float32), ((b, hkv, m), jnp.float32),
+        ((b, hkv, m, dh // 2), jnp.uint8), ((b,), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_lop_launches_keep_their_instruction_names(compile_for):

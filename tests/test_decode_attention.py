@@ -32,8 +32,9 @@ def _setup(b, h, hkv, m, dh):
 @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2)])
 @pytest.mark.parametrize("shared", [False, True])
 @pytest.mark.parametrize("window", [0, 48])
-def test_fused_lop_matches_ref(h, hkv, shared, window):
-    b, m, dh, block, k_keep = 2, 256, 32, 32, 3
+@pytest.mark.parametrize("dh", [32, 100])      # 100: d/2 = 50, off the lanes
+def test_fused_lop_matches_ref(h, hkv, shared, window, dh):
+    b, m, block, k_keep = 2, 256, 32, 3
     args = _setup(b, h, hkv, m, dh)
     new_len = jnp.asarray([197, 64], jnp.int32)
     kw = dict(block=block, k_keep=k_keep, window=window, use_lop=True,

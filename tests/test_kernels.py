@@ -4,9 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.lop import lop_features, pack_features
+from repro.core.lop import (LO_ZERO, features_to_pot, lop_features,
+                            pack_features, unpack_features)
 from repro.core.ternary import make_ternary_weight
 from repro.kernels import ops, ref
+from repro.kernels.lop_scores import nibble_planes_to_pot
 
 rng = np.random.default_rng(42)
 
@@ -58,8 +60,30 @@ def test_rmsnorm_kernel_rows_independent_of_call_size():
         assert (np.asarray(norm(x[i:i + 1]))[0] == whole[i]).all(), i
 
 
+def test_nibble_planes_decode_every_byte():
+    """Every packed byte decodes, plane by plane, to the pot values of its
+    two features: the low nibble is feature 2i, the high nibble 2i + 1.
+    The 256 bytes hold every (sgn‖LO) pair: zero (LO_ZERO) under both
+    signs, and ±2^LO for every LO."""
+    packed = jnp.arange(256, dtype=jnp.int32).astype(jnp.uint8)[:, None]
+    even, odd = nibble_planes_to_pot(packed)             # [256, 1] each
+    assert even.dtype == odd.dtype == jnp.int8
+    want = np.asarray(features_to_pot(unpack_features(packed)))  # [256, 2]
+    assert (np.asarray(even)[:, 0] == want[:, 0]).all()
+    assert (np.asarray(odd)[:, 0] == want[:, 1]).all()
+
+    def nibble_pot(n):
+        lo, sgn = n & 0x7, n >> 3
+        return 0 if lo == LO_ZERO else (-1) ** sgn * 2 ** min(lo, 6)
+    assert [int(v) for v in np.asarray(even)[:, 0]] == [
+        nibble_pot(b & 0xF) for b in range(256)]
+    assert [int(v) for v in np.asarray(odd)[:, 0]] == [
+        nibble_pot(b >> 4) for b in range(256)]
+
+
 @pytest.mark.parametrize("g,m,d", [(12, 1024, 128), (1, 512, 64),
-                                   (40, 2048, 128)])
+                                   (40, 2048, 128), (12, 1024, 100),
+                                   (1, 512, 100)])
 def test_lop_scores_kernel(g, m, d):
     q = jnp.asarray(rng.integers(-127, 128, (g, d)), jnp.int8)
     kc = jnp.asarray(rng.integers(-127, 128, (m, d)), jnp.int8)
