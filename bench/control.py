@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-    from bench import harness, stats, traffic
+    from bench import harness, models, stats, traffic
     from bench.reference import check
 
     harness.cache_every_program()
@@ -46,9 +46,10 @@ def main(argv=None) -> int:
     harness.require_chip(cell["chips"])
     conf = harness.load_config(cell["config"])
     mix = traffic.load(cell["traffic"])
+    model = models.load(conf)
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.monotonic()
-        served = harness.Served(conf, mix, seed)
+        served = harness.Served(conf, mix, seed, model)
         try:
             served.warm_up()
             run = served.window(args.seconds)
@@ -60,7 +61,7 @@ def main(argv=None) -> int:
         for side in ("program", "control")[:1 + args.control]:
             v = check.check(conf, mix, run, seed,
                             sample_tokens=harness.CHECK_TOKENS,
-                            control=side == "control")
+                            control=side == "control", model=model)
             out[side] = {"ok": v["ok"], "compared": v["compared"],
                          "read": v["read"]}
         out["seconds"] = time.monotonic() - t0

@@ -1,4 +1,6 @@
-"""Operations and bytes the served kernels need, from shapes.
+"""Operations and bytes the served kernels need, from shapes: the rules
+and the shared kernels. Each architecture's module
+(``bench/models/<name>.py``) counts its own layers with them.
 
 Counts are what the algorithm has to do and move, not what a kernel
 happens to touch: the decode screen reads the 4-bit features of the
@@ -18,8 +20,6 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from bench import weights as W
-
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 F32 = 4
 
@@ -38,7 +38,7 @@ def least_s(ops: float, nbytes: float, pk: dict) -> float:
 
 
 # ---------------------------------------------------------------------------
-# projections (fused QKV, O, fused FFN), per call
+# the ternary projection kernels, per call
 # ---------------------------------------------------------------------------
 
 def qlinear(rows: int, k: int, n: int) -> tuple[float, float]:
@@ -54,56 +54,3 @@ def ffn(rows: int, d: int, f: int) -> tuple[float, float]:
     ops = 2.0 * rows * d * 2 * f + 2.0 * rows * f * d
     nbytes = (d * 2 * f + f * d) / 4 + 2 * f * F32 + rows * 2 * d * F32
     return ops, nbytes
-
-
-def projections_per_layer(arch: dict, rows: int) -> dict:
-    n = W.dims(arch)
-    return {"qkv": qlinear(rows, n["d"], n["qkv"]),
-            "o": qlinear(rows, n["q"], n["d"]),
-            "ffn": ffn(rows, n["d"], n["f"])}
-
-
-# ---------------------------------------------------------------------------
-# decode attention (LOP screen + exact phase), per lane and step
-# ---------------------------------------------------------------------------
-
-def decode_attention(arch: dict, live: int, k_keep: int,
-                     use_lop: bool = True) -> tuple[float, float]:
-    """(int8 ops, bytes) of one lane's decode attention at ``live`` valid
-    cache positions: the screen over every live key's packed features,
-    then exact attention over the live keys of the kept blocks."""
-    n = W.dims(arch)
-    h, hkv, dh = arch["n_heads"], arch["n_kv_heads"], n["hd"]
-    block = arch["lop_block"]
-    exact = live
-    if use_lop and live > k_keep * block:
-        # the fewest live keys k_keep blocks can hold: the partial last
-        # block and k_keep - 1 whole ones
-        exact = (k_keep - 1) * block + (live - (live - 1) // block * block)
-    kv_row = 2 * dh + 2 * F32                      # int8 K, V + 2 scales
-    ops = 4.0 * h * exact * dh
-    nbytes = hkv * exact * kv_row + h * dh * (1 + F32)   # q int8, out f32
-    if use_lop and live > k_keep * block:       # the screen can prune
-        ops += 2.0 * h * live * dh
-        nbytes += hkv * live * dh / 2
-    return ops, nbytes
-
-
-# ---------------------------------------------------------------------------
-# a whole decode step: model operations per token
-# ---------------------------------------------------------------------------
-
-def ternary_params(arch: dict) -> int:
-    n = W.dims(arch)
-    per_layer = n["d"] * n["qkv"] + n["q"] * n["d"] + 3 * n["d"] * n["f"]
-    return arch["n_layers"] * per_layer
-
-
-def decode_token_ops(arch: dict, live: int, k_keep: int,
-                     use_lop: bool = True) -> float:
-    """Operations one decoded token needs: 2 per ternary weight, 2 per
-    LM-head weight, and the attention of every layer at ``live``."""
-    n = W.dims(arch)
-    att, _ = decode_attention(arch, live, k_keep, use_lop)
-    return (2.0 * ternary_params(arch) + 2.0 * n["d"] * n["v"]
-            + arch["n_layers"] * att)

@@ -3,13 +3,16 @@
 The cell, its configuration and its traffic mix are found by name:
 ``BENCHMARK.json`` names the cell's ``config`` and ``traffic``, read from
 ``bench/configs/<config>.json`` and ``bench/traffic/<traffic>.json``;
-each per-layer metric is read by ``bench/metrics/<name>.py``. A new cell
-is data files and entries only.
+the configuration names its architecture's module
+(``bench/models/<model>.py``, ``dense`` by default), which makes the
+weights, runs the plain reference and counts the kernels' work; each
+per-layer metric is read by ``bench/metrics/<name>.py``. A new cell is
+data files and entries only, a new architecture a module besides.
 
 A run:
 
 1. refuses anything but the accelerator with the Pallas kernels;
-2. makes the weights on the device from the seed (``bench/weights.py``);
+2. makes the weights on the device from the seed (the model module);
 3. builds ``PooledEngine`` and ``Scheduler`` from the configuration's
    ``server`` settings and serves them with ``serve_threaded`` on a
    loopback port;
@@ -34,7 +37,8 @@ import sys
 import time
 from pathlib import Path
 
-from bench import stats, traffic
+from bench import models, stats, traffic
+from bench import trace as T
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -154,22 +158,17 @@ class CompileCounter:
 # the served system
 # ---------------------------------------------------------------------------
 
-def model_config(conf: dict):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(name=conf["name"], **conf["arch"])
-
-
-def build_server(conf: dict, seed: int, registry):
-    """→ (scheduler, served weights), the weights made from ``seed``."""
+def build_server(conf: dict, seed: int, registry, model):
+    """→ (scheduler, served weights), the weights made from ``seed`` by
+    the configuration's model module ``model``."""
     import jax
 
-    from bench import weights
     from repro.serving.api import PooledEngine
     from repro.serving.scheduler import Scheduler
 
-    cfg = model_config(conf)
+    cfg = model.model_config(conf)
     srv = conf["server"]
-    qp = weights.served_params(conf["arch"], seed)
+    qp = model.served_params(conf["arch"], seed)
     jax.block_until_ready(qp)
     engine = PooledEngine(cfg, qp, max_len=srv["max_len"],
                           use_lop=srv["use_lop"],
@@ -200,9 +199,10 @@ def warm_up(port: int, conf: dict, mix: dict, seed: int) -> None:
 
 class Served:
     """The served system of one seed: weights, scheduler and the loopback
-    HTTP server, and the load generator that drives it."""
+    HTTP server, and the load generator that drives it; ``model`` is the
+    configuration's model module."""
 
-    def __init__(self, conf: dict, mix: dict, seed: int):
+    def __init__(self, conf: dict, mix: dict, seed: int, model):
         from repro.serving.frontend import serve_threaded
         from repro.serving.metrics import MetricsRegistry
 
@@ -211,7 +211,8 @@ class Served:
         self.srv = None
         try:
             self.registry = MetricsRegistry()
-            self.sched, self.qp = build_server(conf, seed, self.registry)
+            self.sched, self.qp = build_server(conf, seed, self.registry,
+                                               model)
             self.srv = serve_threaded(self.sched, model_name=conf["name"],
                                       registry=self.registry)
         except BaseException:
@@ -219,7 +220,16 @@ class Served:
             raise
 
     def warm_up(self) -> None:
+        """:func:`warm_up`, then wait until the scheduler has retired the
+        warm-up lane: the stream ends with its last token, and the lane's
+        eviction, a program the window runs too, follows on the
+        scheduler's thread."""
         warm_up(self.srv.port, self.conf, self.mix, self.seed)
+        deadline = time.monotonic() + DRAIN_S
+        while self.sched.n_active:
+            if time.monotonic() > deadline:
+                raise RuntimeError("the warm-up lane was never retired")
+            time.sleep(0.01)
 
     def window(self, seconds: float, during=None) -> dict:
         """Send the mix for ``seconds``; ``during(t_go)`` runs on this
@@ -265,12 +275,14 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
              t_process: float, root: Path = ROOT,
              check_chip: bool = True, configs_dir: Path | None = None,
              traffic_dir: Path | None = None,
-             metrics_dir: Path | None = None) -> dict:
+             metrics_dir: Path | None = None,
+             models_dir: Path | None = None) -> dict:
     """Everything of one run; → the result object of the last line."""
     spec = load_spec(root)
     cell = find_cell(spec, cell_name)
     conf = load_config(cell["config"], configs_dir or BENCH_DIR / "configs")
     mix = traffic.load(cell["traffic"], traffic_dir or traffic.DIR)
+    model = models.load(conf, models_dir)
 
     import jax
 
@@ -279,7 +291,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter()
 
     t0 = time.monotonic()
-    served = Served(conf, mix, seed)
+    served = Served(conf, mix, seed, model)
     try:
         t1 = time.monotonic()
         served.warm_up()
@@ -319,13 +331,15 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     log(f"end to end: {json.dumps(e2e)}")
     if trace:
         tr = run["during"]
-        red = tr.reduce()
+        table = T.load_kernel_table(model.KERNELS)
+        red = tr.reduce(table)
         result["device"]["busy_s"] = red.busy_s
         result["device"]["window_s"] = red.window_s
         result["breakdown"] = {"device_ops": red.top_ops,
                                "idle_gaps": red.top_gaps}
         ctx = Context(conf=conf, cell=cell, run=run, reduction=red,
-                      trace_span=(tr.t0, tr.t1), device_kind=dev.device_kind)
+                      trace_span=(tr.t0, tr.t1), device_kind=dev.device_kind,
+                      model=model, table=table)
         metrics = {}
         for m in cell_metrics(spec, cell, "per_layer"):
             value = load_reader(m["name"], metrics_dir
@@ -340,7 +354,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                          for k, v in metrics.items()}
 
     from bench.reference import check
-    verdict = check.check(conf, mix, run, seed, sample_tokens=CHECK_TOKENS)
+    verdict = check.check(conf, mix, run, seed, sample_tokens=CHECK_TOKENS,
+                          model=model)
     result["correct"] = bool(verdict["ok"] and not run["unfinished"]
                              and recs)
     result["compared"] = verdict["compared"]
@@ -352,7 +367,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 class Context:
     """What a per-layer reader gets: the configuration, the cell, the
     load generator's records, the trace reduction, the host span of the
-    trace, and the device kind (for the peaks)."""
+    trace, the device kind (for the peaks), the configuration's model
+    module (for the counts) and the kernel name table."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -382,9 +398,8 @@ class trace_window:
             raise RuntimeError(f"no trace written under {TRACE_DIR}")
         return found[-1]
 
-    def reduce(self):
-        from bench import trace as T
-        table = T.load_kernel_table()
+    def reduce(self, table: dict):
+        """The trace reduced by the kernel name ``table``."""
         tr = T.load(self.path(), (self.t1 - self.t0) * 1e9)
         return T.reduce(tr, table["kernels"], table["programs"],
                         table.get("containers", ()))

@@ -1,8 +1,8 @@
 """Share of its roofline the decode attention reaches (both launches:
 the LOP screen and the exact phase), in %: the least time of every
-decode lane-step in the traced span (``bench/cost.py``, at each step's
-live length) over the kernels' summed device time inside decode-step
-programs."""
+decode lane-step in the traced span (the model module's
+``decode_attention_calls``, at each step's live length) over the
+kernels' summed device time inside decode-step programs."""
 
 from bench import cost, readers
 
@@ -17,6 +17,7 @@ def read(ctx):
     use_lop = ctx.conf["server"]["use_lop"]
     least = 0.0
     for live in lives:
-        ops, nbytes = cost.decode_attention(arch, live, kk, use_lop)
-        least += cost.least_s(ops, nbytes, pk) * arch["n_layers"]
+        for ops, nbytes, n in ctx.model.decode_attention_calls(
+                arch, live, kk, use_lop):
+            least += cost.least_s(ops, nbytes, pk) * n
     return 100.0 * least / secs

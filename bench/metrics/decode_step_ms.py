@@ -1,5 +1,6 @@
 """Mean device span of one run of the decode-step program (every active
-lane one token), from the trace's program events."""
+lane one token), over its whole runs in the traced span: none that the
+profiler's start or stop cut (``trace.whole_runs``)."""
 
 from bench import readers
 

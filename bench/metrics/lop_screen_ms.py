@@ -7,4 +7,4 @@ from bench import spans
 
 
 def read(ctx):
-    return spans.lop_screen_ms(spans.trace(ctx))
+    return spans.lop_screen_ms(spans.trace(ctx), ctx.table["programs"])

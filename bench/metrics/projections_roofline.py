@@ -1,6 +1,7 @@
 """Share of their roofline the ternary projections reach in decode
-steps (fused QKV and O through the qlinear kernel, the fused FFN), in
-%: per call the least time of its shapes (``bench/cost.py``; rows = the
+steps (for a dense model fused QKV and O through the qlinear kernel,
+the fused FFN through the ffn kernel), in %: per call the least time of
+its shapes (the model module's ``decode_projection_calls``; rows = the
 mean number of lanes decoded per step in the traced span), over the
 kernels' summed device time inside decode-step programs."""
 
@@ -8,16 +9,19 @@ from bench import cost, readers
 
 
 def read(ctx):
-    q_s, q_calls = readers.kernel_seconds(ctx, "qlinear", "decode_step")
-    f_s, f_calls = readers.kernel_seconds(ctx, "ffn", "decode_step")
     steps = len(ctx.reduction.program_spans.get("decode_step", ()))
     lives = readers.decoded_live_lengths(ctx)
-    if not (q_calls and f_calls and steps and lives):
+    if not (steps and lives):
         return None
-    rows = len(lives) / steps
+    per = ctx.model.decode_projection_calls(ctx.conf["arch"],
+                                            len(lives) / steps)
     pk = readers.peaks(ctx)
-    per = cost.projections_per_layer(ctx.conf["arch"], rows)
-    least = lambda kind: cost.least_s(*per[kind], pk)
-    total = (q_calls / 2 * (least("qkv") + least("o"))
-             + f_calls * least("ffn"))
-    return 100.0 * total / (q_s + f_s)
+    total = secs = 0.0
+    for kernel, shapes in per.items():
+        k_s, k_calls = readers.kernel_seconds(ctx, kernel, "decode_step")
+        if not k_calls:
+            return None
+        total += k_calls / len(shapes) * sum(cost.least_s(*s, pk)
+                                             for s in shapes)
+        secs += k_s
+    return 100.0 * total / secs

@@ -2,17 +2,20 @@
 
 A reader is ``read(ctx) -> float | None`` (see ``harness.Context``); it
 returns None when its run holds nothing to read, and the harness then
-leaves the metric out of the result line.
+leaves the metric out of the result line. Counts of the architecture's
+work come from its model module, ``ctx.model`` (``bench/models/``).
 """
 
 from __future__ import annotations
 
 from bench import cost
-from bench.reference import model
+from bench.reference import model as R
 
 
 def program_mean_ms(ctx, program: str):
-    spans = ctx.reduction.program_spans.get(program)
+    """Mean device span of the program's whole runs in the traced span
+    (``trace.whole_runs``), in ms."""
+    spans = ctx.reduction.whole_spans.get(program)
     if not spans:
         return None
     return 1e3 * sum(spans) / len(spans)
@@ -36,8 +39,8 @@ def decoded_live_lengths(ctx) -> list[int]:
 
 def k_keep(ctx) -> int:
     arch = ctx.conf["arch"]
-    cap = model.capacity_of(arch, ctx.conf["server"]["max_len"])
-    return model.k_keep_blocks(arch, cap)
+    cap = R.capacity_of(arch, ctx.conf["server"]["max_len"])
+    return R.k_keep_blocks(arch, cap)
 
 
 def peaks(ctx) -> dict:

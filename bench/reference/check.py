@@ -2,8 +2,9 @@
 
 Once the window has closed and the server is freed, a sample of the
 finished requests, drawn from the seed with the longest among them, is
-run through the plain reference (``model.logits``) over each prompt and
-its served tokens. Every served token is greedy, so the reference's best
+run through the plain reference (the ``logits`` of the configuration's
+model module, ``bench/models/``) over each prompt and its served
+tokens. Every served token is greedy, so the reference's best
 logit at its position should be that token's, to within rounding. The
 number compared is the widest gap by which a served token's reference
 logit lies below the reference's best (``max_logit_gap``); its limit is
@@ -13,7 +14,7 @@ readings over a dozen seeds and the fp8 control's (``PERF.md``).
 ``check(..., control=True)`` reads the control through the same
 comparison: at each position of the same prompts and served tokens it
 compares the token that the reference computed in fp8 puts first
-(``model.logits(control=True)``) in place of the served one.
+(``logits(control=True)``) in place of the served one.
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ import random
 import numpy as np
 
 from bench import stats, traffic
-from bench.reference import model
+from bench.reference import model as R
 
 
 def pick_sample(records: list, seed: int, tokens: int) -> list:
@@ -53,18 +54,20 @@ def pass_length(conf: dict, mix: dict) -> int:
     return -(-n // b) * b
 
 
-def gaps(conf: dict, mix: dict, seed: int, rec: dict, control=False):
-    """(reference logits, served tokens) of one finished request; with
-    ``control`` the fp8 control's logits in place of the reference's."""
+def gaps(conf: dict, mix: dict, seed: int, rec: dict, model,
+         control=False):
+    """(reference logits, served tokens) of one finished request, by the
+    configuration's model module ``model``; with ``control`` the fp8
+    control's logits in place of the reference's."""
     arch = conf["arch"]
-    cap = model.capacity_of(arch, conf["server"]["max_len"])
+    cap = R.capacity_of(arch, conf["server"]["max_len"])
     prompt = traffic.prompt(seed, rec["stream"], rec["index"],
                             rec["prompt_len"], arch["vocab"])
     toks = np.asarray(rec["tokens"], np.int64)
     seq = prompt + [int(t) for t in toks[:-1]]
     lg = model.logits(arch, seed, seq, len(prompt),
                       length=pass_length(conf, mix),
-                      k_keep=model.k_keep_blocks(arch, cap),
+                      k_keep=R.k_keep_blocks(arch, cap),
                       n_rows=traffic.max_tokens(mix), control=control)
     return lg[:len(toks)], toks
 
@@ -85,26 +88,27 @@ def gap_stats(g: np.ndarray) -> dict:
             "argmax_disagreement": float((g > 0).mean())}
 
 
-def sample_gaps(conf: dict, mix: dict, seed: int, rec: dict,
+def sample_gaps(conf: dict, mix: dict, seed: int, rec: dict, model,
                 control: bool) -> np.ndarray:
     """Per-token gaps of one request: of its served tokens, or with
     ``control`` of the fp8 control's first choices."""
-    lg, toks = gaps(conf, mix, seed, rec)
+    lg, toks = gaps(conf, mix, seed, rec, model)
     if control:
-        toks = gaps(conf, mix, seed, rec, control=True)[0].argmax(-1)
+        toks = gaps(conf, mix, seed, rec, model, control=True)[0].argmax(-1)
     return token_gaps(lg, toks)
 
 
-def check(conf: dict, mix: dict, run: dict, seed: int, *,
+def check(conf: dict, mix: dict, run: dict, seed: int, *, model,
           sample_tokens: int, control: bool = False) -> dict:
     """→ {"ok", "compared": {name: {"value", "limit"}}, "read": the
-    gap statistics, "lines"}. The configuration's ``limits`` name the
-    numbers compared."""
+    gap statistics, "lines"}, by the configuration's model module
+    ``model``. The configuration's ``limits`` name the numbers
+    compared."""
     recs = stats.due_in_window(run)
     failed = sum(not stats.ok(r) for r in recs)
     sample = pick_sample(recs, seed, sample_tokens)
     g = np.concatenate([np.zeros(0)] + [
-        sample_gaps(conf, mix, seed, r, control) for r in sample])
+        sample_gaps(conf, mix, seed, r, model, control) for r in sample])
     read = gap_stats(g)
     compared = {name: {"value": read[name], "limit": float(limit)}
                 for name, limit in conf["limits"].items()}

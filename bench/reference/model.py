@@ -1,57 +1,47 @@
-"""Plain reference of the served model, in straightforward jax.numpy.
+"""What every architecture's plain reference shares, in straightforward
+jax.numpy. It imports nothing of the program.
 
-It computes, for one request, the logits the served path should have
-produced at each position that emitted a token: the last prompt position
-(prefill) and every decoded position after it. It imports nothing of
-the program; its weights come from ``bench/weights.py`` in plain form
-(ternary int8 values and per-projection scales), regenerated from the
-run's seed.
+Each architecture's module (``bench/models/<name>.py``) writes its own
+forward pass, ``logits``, from these pieces, reading its weights in the
+plain form that ``bench/weights.py``'s draws make from the run's seed:
 
-What it computes, per decoder layer (the configuration file's ``arch``):
-
-* pre-norm (RMSNorm, or LayerNorm with bias), eps 1e-6;
-* BitLinear projections: the input row quantized to int8 by its absmax
-  (scale = max(|x|, 1e-5) / 127, round half to even, clip ±127), an
-  integer product with the ternary matrix, dequantized by the row scale
-  times the projection's γ. The FFN is silu(gate) · up, whose hidden row
-  crosses its own int8 barrier before the down projection;
-* rotary embedding on the first and second half of each head (theta
-  from the file), then q, k and v quantized to int8 per token and head;
-* attention with the int8 K/V cache semantics of the system: logits
-  q·k · s_q · s_k / sqrt(dh), f32 softmax, values v · s_v. Prompt
-  positions attend to every earlier position. A decoded position t
-  attends only to the blocks (``lop_block`` tokens) that the LOP screen
-  keeps: the screen scores each valid key by pot(q)·pot(k)
-  (sign · 2^floor(log2 |x|) of the int8 values), a block scores the
-  maximum over its valid keys, and the comparison-free selector (64
-  linear buckets between the row's least and greatest block score,
-  buckets above the cut bin first, then the cut bin, each in index
-  order) keeps ``k_keep`` blocks;
-* after the last layer the final norm and the LM head, f32 weights and
-  activations.
+* :func:`norm`: RMSNorm, or LayerNorm with bias, eps 1e-6;
+* :func:`bitlinear`: a BitLinear projection: the input row quantized to
+  int8 by its absmax (:func:`quant`: scale = max(|x|, 1e-5) / 127,
+  round half to even, clip ±127), an integer product with the ternary
+  matrix, dequantized by the row scale times the projection's γ;
+* :func:`rope`: rotary embedding on the first and second half of each
+  head;
+* :func:`attend`: attention with the int8 K/V cache semantics of the
+  system: logits q·k · s_q · s_k / sqrt(dh), f32 softmax, values v · s_v.
+  Prompt positions attend to every earlier position. A decoded position
+  t attends only to the blocks (``lop_block`` tokens) that the LOP
+  screen keeps: the screen scores each valid key by pot(q)·pot(k)
+  (:func:`pot`: sign · 2^floor(log2 |x|) of the int8 values), a block
+  scores the maximum over its valid keys, and the comparison-free
+  selector (:func:`select_blocks`: 64 linear buckets between the row's
+  least and greatest block score, buckets above the cut bin first, then
+  the cut bin, each in index order) keeps ``k_keep`` blocks
+  (:func:`k_keep_blocks` of the cache's :func:`capacity_of`).
 
 Every floating-point value is float32 and every matrix product runs at
 ``precision="highest"``: the reference sits above the precision the
 published checkpoints state (float16 and bfloat16).
 
-With ``control=True`` it is the control, the next precision below the
-stated one: every floating-point weight and every activation an
-operation produces is held in fp8 e4m3 (:func:`fp8`: activations with
-one scale per row, weights per tensor, the embedding per row and the LM
-head per column), the arithmetic between them in float32. A program
-that computed in fp8 would read as this control does, and the
-comparison has to fail it.
+The control is the next precision below the stated one: every
+floating-point weight and every activation an operation produces is
+held in fp8 e4m3 (:func:`fp8`: activations with one scale per row,
+weights per tensor, the embedding per row and the LM head per column,
+:func:`low_weights`), the arithmetic between them in float32. A forward
+pass rounds what each operation produces with ``r``: :func:`fp8` in the
+control, :func:`exact` otherwise. A program that computed in fp8 would
+read as this control does, and the comparison has to fail it.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-from bench import weights as W
 
 EPS_NORM = 1e-6
 EPS_QUANT = 1e-5
@@ -86,18 +76,18 @@ def fp8(x, axis=-1):
     return jnp.ldexp(jnp.round(jnp.ldexp(y, 4 - e)), e - 4) * s
 
 
-def _exact(x, axis=-1):
+def exact(x, axis=-1):
     return x
 
 
-def _quant(x):
+def quant(x):
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, EPS_QUANT) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
 
-def _norm(p, x, kind):
+def norm(p, x, kind):
     if kind == "rmsnorm":
         ms = jnp.mean(x * x, axis=-1, keepdims=True)
         return x * jax.lax.rsqrt(ms + EPS_NORM) * p["g"]
@@ -106,14 +96,14 @@ def _norm(p, x, kind):
     return (x - mu) * jax.lax.rsqrt(var + EPS_NORM) * p["g"] + p["b"]
 
 
-def _bitlinear(x, w, gamma):
+def bitlinear(x, w, gamma):
     """x f32 [T, k]; w int8 [k, n]; gamma scalar or [n]."""
-    xq, xs = _quant(x)
+    xq, xs = quant(x)
     acc = jnp.dot(xq, w, preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * xs * gamma
 
 
-def _rope(x, theta: float):
+def rope(x, theta: float):
     """x [T, H, dh] → rotated on (first half, second half) pairs."""
     t, _, dh = x.shape
     half = dh // 2
@@ -125,7 +115,7 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _pot(x):
+def pot(x):
     """sign(x) · 2^floor(log2 |x|) of int8 x, 0 for 0 (int8)."""
     v = jnp.abs(x.astype(jnp.int32))
     lo = ((v >= 2).astype(jnp.int32) + (v >= 4) + (v >= 8) + (v >= 16)
@@ -159,7 +149,7 @@ def select_blocks(s, k: int):
     return rank < k
 
 
-def _attend(qi, qs, ki, ks, vi, vs, n_prompt, *, block, k_keep, r):
+def attend(qi, qs, ki, ks, vi, vs, n_prompt, *, block, k_keep, r):
     """One group of heads. qi int8 [G, T, dh], qs [G, T, 1]; ki/vi int8
     [G, T, dh]; ks/vs [G, T] → [G, T, dh]; ``r`` rounds what each
     operation produces (:func:`fp8` in the control)."""
@@ -170,7 +160,7 @@ def _attend(qi, qs, ki, ks, vi, vs, n_prompt, *, block, k_keep, r):
                    preferred_element_type=jnp.int32).astype(jnp.float32)
     s = r(s * qs * ks[:, None, :] * dh ** -0.5)
 
-    sc = jnp.einsum("gtd,gmd->gtm", _pot(qi), _pot(ki),
+    sc = jnp.einsum("gtd,gmd->gtm", pot(qi), pot(ki),
                     preferred_element_type=jnp.int32)
     sc = jnp.where(causal, sc, INT32_MIN)
     nb = t // block
@@ -185,61 +175,8 @@ def _attend(qi, qs, ki, ks, vi, vs, n_prompt, *, block, k_keep, r):
     return r(jnp.einsum("gtm,gmd->gtd", p, vf))
 
 
-@functools.partial(jax.jit, static_argnames=("arch_items", "k_keep", "low"))
-def _layer(x, lw, n_prompt, *, arch_items, k_keep, low):
-    arch = W.thaw(arch_items)
-    r = fp8 if low else _exact
-    n = W.dims(arch)
-    t = x.shape[0]
-    h, hkv, dh = arch["n_heads"], arch["n_kv_heads"], n["hd"]
-
-    hn = r(_norm(lw["ln1"], x, arch["norm"]))
-    gcol = jnp.concatenate([jnp.full((n["q"],), lw["g_q"]),
-                            jnp.full((n["kv"],), lw["g_k"]),
-                            jnp.full((n["kv"],), lw["g_v"])])
-    qkv = r(_bitlinear(hn, lw["wqkv"], gcol))
-    q = qkv[:, :n["q"]].reshape(t, h, dh)
-    k = qkv[:, n["q"]:n["q"] + n["kv"]].reshape(t, hkv, dh)
-    v = qkv[:, n["q"] + n["kv"]:].reshape(t, hkv, dh)
-    theta = float(arch["rope_theta"])
-    q, k = r(_rope(q, theta)), r(_rope(k, theta))
-    qi, qs = _quant(q)                                       # [T, H, dh]
-    ki, ks = _quant(k)
-    vi, vs = _quant(v)
-    rep = h // hkv
-    to_heads = lambda a: jnp.repeat(jnp.moveaxis(a, 1, 0), rep, axis=0)
-    qi, qs = jnp.moveaxis(qi, 1, 0), jnp.moveaxis(qs, 1, 0)
-    ki, vi = to_heads(ki), to_heads(vi)
-    ks, vs = to_heads(ks)[..., 0], to_heads(vs)[..., 0]
-    grp = HEAD_GROUP if h % HEAD_GROUP == 0 else 1
-    split = lambda a: a.reshape((h // grp, grp) + a.shape[1:])
-    o = jax.lax.map(
-        lambda a: _attend(*a, n_prompt, block=arch["lop_block"],
-                          k_keep=k_keep, r=r),
-        tuple(split(a) for a in (qi, qs, ki, ks, vi, vs)))
-    o = jnp.moveaxis(o.reshape(h, t, dh), 0, 1).reshape(t, n["q"])
-    x = r(x + r(_bitlinear(o, lw["wo"], lw["g_o"])))
-
-    hn = r(_norm(lw["ln2"], x, arch["norm"]))
-    f = n["f"]
-    gcol = jnp.concatenate([jnp.full((f,), lw["g_g"]),
-                            jnp.full((f,), lw["g_u"])])
-    gu = r(_bitlinear(hn, lw["wgu"], gcol))
-    hid = r(jax.nn.silu(gu[:, :f]) * gu[:, f:])
-    return r(x + r(_bitlinear(hid, lw["wd"], lw["g_d"])))
-
-
-@functools.partial(jax.jit, static_argnames=("arch_items", "n_rows", "low"))
-def _head(x, top, first, *, arch_items, n_rows, low):
-    arch = W.thaw(arch_items)
-    r = fp8 if low else _exact
-    rows = jnp.clip(first + jnp.arange(n_rows), 0, x.shape[0] - 1)
-    xr = r(_norm(top["ln_f"], x[rows], arch["norm"]))
-    return r(jnp.dot(xr, top["head"], preferred_element_type=jnp.float32))
-
-
 @jax.jit
-def _low_weights(tree):
+def low_weights(tree):
     """The control's weights: every float leaf in fp8 with one scale per
     tensor, the embedding's per row and the LM head's per column."""
     out = jax.tree.map(
@@ -249,31 +186,3 @@ def _low_weights(tree):
         out["embed"] = fp8(tree["embed"])
         out["head"] = fp8(tree["head"], 0)
     return out
-
-
-def logits(arch: dict, seed: int, tokens, n_prompt: int, *, length: int,
-           k_keep: int, n_rows: int, control: bool = False) -> np.ndarray:
-    """Logits [n_rows, vocab] at positions n_prompt-1, n_prompt, ... of
-    the sequence ``tokens`` (prompt ‖ served tokens), padded to
-    ``length`` positions (a multiple of ``lop_block``) so that every
-    request of a cell shares one compiled program. Rows past the
-    sequence are not meaningful. ``control``: the fp8 control (module
-    doc)."""
-    tokens = np.asarray(tokens, np.int32)
-    if len(tokens) > length or length % arch["lop_block"]:
-        raise ValueError(f"{len(tokens)} tokens in a length-{length} pass")
-    items = W.frozen(arch)
-    weights = _low_weights if control else (lambda tree: tree)
-    with jax.default_matmul_precision("highest"):
-        data = W.seed_data(seed)
-        top = weights(W.plain_top_jit(items, data))
-        padded = np.zeros((length,), np.int32)
-        padded[:len(tokens)] = tokens
-        x = top["embed"][jnp.asarray(padded)]
-        for key in W.layer_keys(data, arch["n_layers"]):
-            lw = weights(W.plain_layer_jit(items, key))
-            x = _layer(x, lw, jnp.int32(n_prompt), arch_items=items,
-                       k_keep=k_keep, low=control)
-        out = _head(x, top, jnp.int32(n_prompt - 1), arch_items=items,
-                    n_rows=n_rows, low=control)
-    return np.asarray(out)

@@ -12,6 +12,10 @@ The program writes three kinds of name into the profiler trace of a
 * the prefill-chunk program's name, ``jit_prefill_chunk``, on its
   module events.
 
+Programs are found by the name table's ``programs`` patterns
+(``bench/kernels.json`` merged with the architecture module's
+``KERNELS``: ``harness.Context.table``).
+
 The readers of ``lop_screen_ms``, ``prefill_chunk_ms`` and
 ``cycle_idle_ms`` share what is here. A program that writes none of
 these names reads None, not zero.
@@ -30,7 +34,6 @@ from bench.harness import log
 
 SPAN_PREFIX = "repro."
 SCREEN_SCOPE = re.compile(r"\blop_screen\b")
-PREFILL_PROGRAM = re.compile(r"^jit_prefill_chunk\b")
 
 
 @lru_cache(maxsize=1)
@@ -52,16 +55,12 @@ def _inside(e: T.Event, window_ns: float) -> bool:
     return e.start >= 0 and e.end <= window_ns
 
 
-def _decode_patterns() -> list:
-    return T.load_kernel_table()["programs"]["decode_step"]
-
-
-def lop_screen_ms(tr: T.Trace):
+def lop_screen_ms(tr: T.Trace, programs: dict):
     """Device time of the ops marked ``lop_screen`` inside
     decode-step programs that lie wholly in the window (the union of
     their intervals, so an op nested in another counts once), over the
     number of those programs, in ms; None where no such op ran."""
-    pats = _decode_patterns()
+    pats = programs["decode_step"]
     screen_ns, steps = 0.0, 0
     for dev in tr.devices:
         mods = [m for m in dev.modules
@@ -80,11 +79,14 @@ def lop_screen_ms(tr: T.Trace):
     return screen_ns / steps / 1e6
 
 
-def prefill_chunk_ms(tr: T.Trace):
-    """Mean device span of the ``jit_prefill_chunk`` program events that
-    lie wholly in the window, in ms; None where there is none."""
-    spans = [m.dur for dev in tr.devices for m in dev.modules
-             if _inside(m, tr.window_ns) and PREFILL_PROGRAM.match(m.label)]
+def prefill_chunk_ms(tr: T.Trace, programs: dict):
+    """Mean device span of the prefill-chunk program's whole runs in the
+    window (``trace.whole_runs``: none that the profiler's start or stop
+    cut), in ms; None where there is none."""
+    pats = programs["prefill_chunk"]
+    spans = [m.dur for dev in tr.devices
+             for m in T.whole_runs(dev, tr.window_ns)
+             if T.match_any(pats, m.label)]
     if not spans:
         return None
     return sum(spans) / len(spans) / 1e6

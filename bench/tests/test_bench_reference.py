@@ -24,6 +24,7 @@ import pytest
 
 from bench import traffic
 from bench import weights as W
+from bench.models import dense
 from bench.reference import check, model
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
@@ -47,14 +48,13 @@ def tiny(name):
 
 @pytest.fixture(scope="module", params=["bitnet-3b", "stablelm-1.6b"])
 def served(request):
-    from repro.configs.base import ModelConfig
     from repro.serving.api import GenerateRequest, PooledEngine
     from repro.serving.metrics import MetricsRegistry
     from repro.serving.scheduler import Scheduler
 
     conf = tiny(request.param)
-    cfg = ModelConfig(name=conf["name"], **conf["arch"])
-    qp = W.served_params(conf["arch"], SEED)
+    cfg = dense.model_config(conf)
+    qp = dense.served_params(conf["arch"], SEED)
     eng = PooledEngine(cfg, qp, max_len=MAX_LEN)
     sched = Scheduler(cfg, qp, n_slots=3, max_len=MAX_LEN, engine=eng,
                       metrics=MetricsRegistry())
@@ -79,7 +79,7 @@ def ref_logits(conf, prompt, toks):
     arch = conf["arch"]
     cap = model.capacity_of(arch, MAX_LEN)
     seq = list(prompt) + list(toks[:-1])
-    return model.logits(arch, SEED, seq, len(prompt), length=cap,
+    return dense.logits(arch, SEED, seq, len(prompt), length=cap,
                         k_keep=model.k_keep_blocks(arch, cap),
                         n_rows=80)[:len(toks)]
 
@@ -88,7 +88,7 @@ def test_weights_are_the_same_numbers_on_both_sides(served):
     conf, eng, _, _ = served
     arch = conf["arch"]
     key = W.layer_keys(W.seed_data(SEED), arch["n_layers"])[1]
-    plain = W.plain_layer_jit(W.frozen(arch), key)
+    plain = dense.plain_layer_jit(W.frozen(arch), key)
     packed = eng.qp["layers"]["ffn"]["down_packed"][1]
     from repro.core.ternary import unpack_ternary
     down = np.asarray(unpack_ternary(packed, packed.shape[0] * 4))
@@ -113,7 +113,7 @@ def test_served_tokens_are_the_reference_argmax(served):
     assert gaps.max() <= ARGMAX_TOL
     assert (gaps == 0).mean() >= 0.95
     verdict = check.check(conf, MIX, as_run(prompts, toks), SEED,
-                          sample_tokens=10**6)
+                          model=dense, sample_tokens=10**6)
     assert verdict["ok"] is True
     assert verdict["read"]["max_logit_gap"] == pytest.approx(gaps.max())
 
@@ -121,7 +121,7 @@ def test_served_tokens_are_the_reference_argmax(served):
 def test_fp8_control_fails_the_limit(served):
     conf, _, prompts, toks = served
     verdict = check.check(conf, MIX, as_run(prompts, toks), SEED,
-                          sample_tokens=10**6, control=True)
+                          model=dense, sample_tokens=10**6, control=True)
     assert verdict["ok"] is False
     gap = verdict["compared"]["max_logit_gap"]
     assert gap["value"] > 3 * ARGMAX_TOL and gap["value"] > gap["limit"]

@@ -3,10 +3,14 @@ screen's time per decode step, the prefill-chunk program's span, and
 device idle inside the serve cycles split by the innermost span; each
 None where the program writes none of these names."""
 
+import functools
+
 import pytest
 
 from bench import harness, spans
 from bench import trace as T
+
+PROGRAMS = T.load_kernel_table()["programs"]
 
 
 def ev(name, start, end):
@@ -75,11 +79,13 @@ def synthetic(screen=True, chunk_program="jit_prefill_chunk", cycles=True):
 
 def test_lop_screen_time_per_decode_step():
     # steps in the window: (190 + 10) and (180 + 10, the nested op once)
-    assert spans.lop_screen_ms(synthetic()) == pytest.approx(195e-6)
+    assert spans.lop_screen_ms(synthetic(), PROGRAMS) == \
+        pytest.approx(195e-6)
 
 
 def test_prefill_chunk_span():
-    assert spans.prefill_chunk_ms(synthetic()) == pytest.approx(50e-6)
+    assert spans.prefill_chunk_ms(synthetic(), PROGRAMS) == \
+        pytest.approx(50e-6)
 
 
 def test_cycle_idle_split_by_innermost_span():
@@ -101,8 +107,10 @@ def test_cycle_idle_split_by_innermost_span():
 
 
 @pytest.mark.parametrize("reader,trace", [
-    (spans.lop_screen_ms, synthetic(screen=False)),
-    (spans.prefill_chunk_ms, synthetic(chunk_program="jit_run")),
+    (functools.partial(spans.lop_screen_ms, programs=PROGRAMS),
+     synthetic(screen=False)),
+    (functools.partial(spans.prefill_chunk_ms, programs=PROGRAMS),
+     synthetic(chunk_program="jit_run")),
     (spans.cycle_idle_ms, synthetic(cycles=False)),
     (spans.cycle_idle_ms, T.Trace([], synthetic().host, 1000.0)),
 ], ids=["no-scope", "old-program-name", "no-spans", "no-device"])
@@ -128,6 +136,7 @@ def test_readers_read_the_run_trace(monkeypatch, name, want):
         return synthetic()
 
     monkeypatch.setattr(spans, "_load", load)
-    ctx = harness.Context(run={"during": _Window()})
+    ctx = harness.Context(run={"during": _Window()},
+                          table={"programs": PROGRAMS})
     assert harness.load_reader(name)(ctx) == pytest.approx(want)
     assert asked == [("synthetic.xplane.pb", pytest.approx(1000.0))]

@@ -1,8 +1,10 @@
 """Trace reduction: busy and idle as a union of intervals, kernel time by
-the name table, idle gaps labelled by the host events in them."""
+the name table (with a model module's own names merged in), program
+spans of whole runs, idle gaps labelled by the host events in them."""
 
 import pytest
 
+from bench import harness, spans
 from bench import trace as T
 
 TABLE = T.load_kernel_table()
@@ -25,7 +27,7 @@ def small_trace():
     a prefill chunk [150, 200), a gap to the window's end (250)."""
     dev = T.Device("/device:TPU:0")
     dev.modules = [ev("jit_step_greedy(123)", 0, 100),
-                   ev("jit_run(456)", 150, 200)]
+                   ev("jit_prefill_chunk(456)", 150, 200)]
     dev.ops = [op("while.7", 0, 100),                      # a container
                op("fused_qlinear.8", 0, 30),
                op("fused_decode_attention.8", 20, 50),     # overlaps
@@ -88,3 +90,52 @@ def test_events_outside_the_window_are_clipped():
     assert T.busy_ns(tr.devices[0], tr.window_ns) == 160
     red = T.reduce(tr, KERNELS, PROGRAMS, CONTAINERS)
     assert ("qlinear", None) not in red.kernel_calls
+
+
+def cut_trace():
+    """Window [0, 316): a prefill chunk cut by the profiler's start
+    [0, 16), whole decode steps [20, 120) and [190, 290) around a whole
+    chunk [130, 180), a chunk cut by its stop [300, 316)."""
+    dev = T.Device("/device:TPU:0")
+    dev.modules = [ev("jit_prefill_chunk(2)", 0, 16),
+                   ev("jit_step_greedy(1)", 20, 120),
+                   ev("jit_prefill_chunk(2)", 130, 180),
+                   ev("jit_step_greedy(1)", 190, 290),
+                   ev("jit_prefill_chunk(2)", 300, 316)]
+    dev.ops = [op("fused_qlinear.2", m.start, m.end) for m in dev.modules]
+    return T.Trace([dev], [], 316.0)
+
+
+def test_program_spans_count_whole_runs_only():
+    tr = cut_trace()
+    assert [(m.start, m.end) for m in T.whole_runs(tr.devices[0], 316.0)] \
+        == [(20, 120), (130, 180), (190, 290)]
+    red = T.reduce(tr, KERNELS, PROGRAMS, CONTAINERS)
+    assert red.whole_spans == {"decode_step": [100e-9, 100e-9],
+                               "prefill_chunk": [50e-9]}
+    assert red.program_spans["prefill_chunk"] == \
+        pytest.approx([16e-9, 50e-9, 16e-9])
+    ctx = harness.Context(reduction=red)
+    assert harness.load_reader("decode_step_ms")(ctx) == \
+        pytest.approx(100e-6)
+    assert spans.prefill_chunk_ms(tr, PROGRAMS) == pytest.approx(50e-6)
+
+
+def test_a_model_module_adds_names_to_the_table():
+    extra = {"kernels": {"ffn": ["^%?grouped_ffn(\\.\\d+)? ="],
+                         "mla": ["^%?mla_decode(\\.\\d+)? ="]},
+             "programs": {"decode_step": ["^jit_step_mla\\b"]}}
+    table = T.load_kernel_table(extra)
+    assert table["kernels"]["ffn"] == KERNELS["ffn"] + extra["kernels"]["ffn"]
+    assert list(table["kernels"]) == list(KERNELS) + ["mla"]
+    assert table["programs"]["decode_step"][-1] == "^jit_step_mla\\b"
+    assert table["containers"] == CONTAINERS
+    assert T.load_kernel_table({}) == TABLE
+    tr = small_trace()
+    tr.devices[0].modules[0] = ev("jit_step_mla(7)", 0, 100)
+    tr.devices[0].ops[3] = op("mla_decode.1", 50, 60)
+    tr.devices[0].ops[5] = op("grouped_ffn.3", 80, 100)
+    red = T.reduce(tr, table["kernels"], table["programs"],
+                   table["containers"])
+    assert red.kernel_s[("mla", "decode_step")] == pytest.approx(10e-9)
+    assert red.kernel_s[("ffn", "decode_step")] == pytest.approx(20e-9)

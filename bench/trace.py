@@ -8,11 +8,14 @@ taken from it:
   busy time is the union of their intervals inside the traced window,
   averaged over the devices used;
 * program events (the ``XLA Modules`` line): one per run of a compiled
-  program, so a program's mean device span per call;
+  program, so a program's mean device span per call. A program running
+  when the profiler starts or stops shows as a shorter event, the
+  device's first or last: a span per call reads only the runs between
+  them (:func:`whole_runs`);
 * kernel time: the summed duration of the op events whose name or
   ``tf_op``/``long_name`` stat matches a pattern of the name table
-  (``bench/kernels.json``), split by the program event that contains
-  them;
+  (``bench/kernels.json``, with the names the architecture's module adds
+  in its ``KERNELS``), split by the program event that contains them;
 * idle gaps: the intervals between busy intervals, each labelled by the
   host-side profiler event that covers most of it.
 
@@ -162,14 +165,38 @@ def containing(events: list, inner: list) -> list:
     return out
 
 
-def load_kernel_table(path: Path = KERNELS_FILE) -> dict:
+def load_kernel_table(extra: dict | None = None,
+                      path: Path = KERNELS_FILE) -> dict:
     """{"kernels": {kernel: [regex]}, "programs": {program: [regex]},
-    "containers": [regex]}"""
-    return json.loads(Path(path).read_text())
+    "containers": [regex]}: the common table, with ``extra`` (a model
+    module's ``KERNELS``, of the same form) merged in: its patterns
+    follow the table's under the same name, and its new names follow
+    the table's names."""
+    table = json.loads(Path(path).read_text())
+    for part, more in (extra or {}).items():
+        if isinstance(more, dict):
+            merged = table.setdefault(part, {})
+            for name, pats in more.items():
+                merged[name] = merged.get(name, []) + list(pats)
+        else:
+            table[part] = table.get(part, []) + list(more)
+    return table
 
 
 def match_any(patterns, text: str) -> bool:
     return any(re.search(p, text) for p in patterns)
+
+
+def whole_runs(dev: Device, window_ns: float) -> list:
+    """The program events of ``dev`` in the window that neither the
+    profiler's start nor its stop can have cut: all but the device's
+    first and last program event. (A whole run there is left out too,
+    which costs a mean one sample and no bias.)"""
+    mods = sorted(dev.modules, key=lambda m: (m.start, m.end))
+    if mods:
+        last = max(mods, key=lambda m: m.end)
+        mods = [m for m in mods[1:] if m is not last]
+    return [m for m in mods if m.start >= 0 and m.end <= window_ns]
 
 
 @dataclass
@@ -178,6 +205,7 @@ class Reduction:
     window_s: float
     busy_s: float                       # mean over devices
     program_spans: dict                 # program name → [seconds]
+    whole_spans: dict                   # the same, whole runs only
     kernel_s: dict                      # (kernel, program) → seconds
     kernel_calls: dict                  # (kernel, program) → calls
     top_ops: list                       # [[name, seconds]] ≤ 10
@@ -207,6 +235,7 @@ def reduce(trace: Trace, kernels: dict, programs: dict,
     n = max(1, len(trace.devices))
     busy = sum(busy_ns(d, w) for d in trace.devices) / n
     spans: dict = defaultdict(list)
+    whole: dict = defaultdict(list)
     ksec: dict = defaultdict(float)
     kcalls: dict = defaultdict(int)
     op_time: dict = defaultdict(float)
@@ -218,6 +247,10 @@ def reduce(trace: Trace, kernels: dict, programs: dict,
                 p = program_name(m, programs)
                 if p:
                     spans[p].append(m.dur / 1e9)
+        for m in whole_runs(dev, w):
+            p = program_name(m, programs)
+            if p:
+                whole[p].append(m.dur / 1e9)
         for op, mod in zip(dev.ops, containing(mods, dev.ops)):
             if not (op.start >= 0 and op.end <= w):
                 continue
@@ -235,7 +268,8 @@ def reduce(trace: Trace, kernels: dict, programs: dict,
                 for g in gaps[:10]]
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
     return Reduction(window_s=w / 1e9, busy_s=busy / 1e9,
-                     program_spans=dict(spans), kernel_s=dict(ksec),
+                     program_spans=dict(spans), whole_spans=dict(whole),
+                     kernel_s=dict(ksec),
                      kernel_calls=dict(kcalls),
                      top_ops=[[k, v] for k, v in top_ops],
                      top_gaps=top_gaps)
