@@ -3,7 +3,9 @@
 Families
 --------
   dense   — decoder-only transformer (GQA/MHA, gated FFN)
-  moe     — decoder-only with token-choice top-k MoE FFN
+  moe     — decoder-only with token-choice top-k MoE FFN; optionally
+            DeepSeek-style: shared experts, leading dense layers
+            (``first_k_dense``) and latent attention (``kv_lora_rank``)
   hybrid  — Jamba-style Mamba+attention interleave (1 attn per ``attn_every``)
             with MoE every ``moe_every`` layers
   ssm     — RWKV6 (attention-free; token-mix recurrence + channel-mix)
@@ -43,8 +45,25 @@ class ModelConfig:
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # training dispatch only
     moe_group: int = 2048       # tokens per dispatch group (scanned)
+    d_expert: int = 0           # routed expert width (0 → d_ff)
+    n_shared_experts: int = 0   # always-on experts, one fused FFN
+    first_k_dense: int = 0      # leading layers with a dense FFN of d_ff
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum 1
+    # --- multi-head latent attention (DeepSeek-V2); kv_lora_rank > 0
+    # selects it: one cached latent row c ‖ k_rope per token ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- YaRN rotary scaling (rope_factor > 0 selects it) ---
+    rope_factor: float = 0.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # --- attention extras ---
     swa_window: int = 0         # 0 = full attention
     qkv_bias: bool = False
@@ -88,6 +107,24 @@ class ModelConfig:
         return self.n_heads * self.hd
 
     @property
+    def mla(self) -> bool:
+        """Latent attention: the cache holds one latent row per token."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of the cached MLA row c ‖ k_rope."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def mla_q_dim(self) -> int:
+        return self.n_heads * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.hd
 
@@ -109,7 +146,7 @@ class ModelConfig:
         return True
 
     def is_moe_layer(self, i: int) -> bool:
-        if self.n_experts == 0:
+        if self.n_experts == 0 or i < self.first_k_dense:
             return False
         if self.moe_every:
             return i % self.moe_every == 1
@@ -251,7 +288,8 @@ def _load_all():
         return
     _LOADED = True
     # import side-effect registers each arch
-    from repro.configs import (bitnet_3b, granite_moe_1b_a400m,  # noqa: F401
+    from repro.configs import (bitnet_3b, deepseek_v2_lite,  # noqa: F401
+                               granite_moe_1b_a400m,
                                jamba_1_5_large_398b, llava_next_34b,
                                mistral_nemo_12b, mixtral_8x22b, qwen1_5_110b,
                                qwen1_5_32b, rwkv6_1_6b, stablelm_1_6b,

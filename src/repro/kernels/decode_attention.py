@@ -63,6 +63,10 @@ Modes (all static):
     instead of G·K.
   * ``return_stats``   — also emit the raw (m, ℓ) softmax stats so the
     SP path can merge shards flash-decoding style without recomputing.
+  * ``v_cols``         — values are the first ``v_cols`` columns of the
+    key rows (latent attention: one cached row c ‖ k_rope serves as key,
+    and its c part as value, with the key's scale): no V operands, and
+    each kept block is fetched once.
 
 Interpret mode is the CPU test path; ``tests/test_chip_compile.py``
 compiles both forms with Mosaic for a v5e at bitnet-3b widths. Every op
@@ -75,16 +79,15 @@ implementation it replaced.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.core.lop import DEFAULT_N_BUCKETS, comparison_free_rank, pot
+from repro.core.marks import scope
 from repro.kernels.lop_scores import plane_scores
 
 NEG_INF = -1e30
@@ -189,12 +192,28 @@ def _candidates(rank, k_keep: int, nb: int):
 # LOP launch 2: exact attention over the selected blocks
 # ---------------------------------------------------------------------------
 
+def _values(k_ref, v_ref, ks_ref, vs_ref, v_cols):
+    """The dequantized value tile of the current block, f32 [block, dv]:
+    the V operand's, or with ``v_cols`` the key rows' first columns."""
+    if v_cols:
+        return k_ref[0][:, :v_cols].astype(jnp.float32) * ks_ref[0]
+    return v_ref[0].astype(jnp.float32) * vs_ref[0]
+
+
+def _kv_refs(refs, v_cols):
+    """(k, v, k scale, v scale) refs of a launch; V is None with v_cols."""
+    if v_cols:
+        k_ref, ks_ref = refs[:2]
+        return k_ref, None, ks_ref, None, refs[2:]
+    return refs[0], refs[1], refs[2], refs[3], refs[4:]
+
+
 def _lop_exact_kernel(nl_ref, po_ref, id_ref, gate_ref, qi_ref, qs_ref,
-                      k_ref, v_ref, ks_ref, vs_ref, o_ref, *rest, g, n_cand,
-                      hkv, block, k_keep, window, softmax_scale,
-                      shared_select, return_stats):
+                      *refs, g, n_cand, hkv, block, k_keep, window,
+                      softmax_scale, shared_select, return_stats, v_cols):
     """Grid (b·hkv, n_cand): fold candidate c's K/V block (fetched by the
     pipeline from the scalar-prefetched block id) into the softmax state."""
+    k_ref, v_ref, ks_ref, vs_ref, (o_ref, *rest) = _kv_refs(refs, v_cols)
     if return_stats:
         m_out, l_out = rest[0], rest[1]
         rest = rest[2:]
@@ -244,7 +263,7 @@ def _lop_exact_kernel(nl_ref, po_ref, id_ref, gate_ref, qi_ref, qs_ref,
         tstart = jnp.clip(nl - window - blk_start, 0, block) if window else 0
         t = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where((t >= tstart) & (t < end), s, NEG_INF)
-        v_deq = v_ref[0].astype(jnp.float32) * vs_ref[0]
+        v_deq = _values(k_ref, v_ref, ks_ref, vs_ref, v_cols)
         _online_update(s, v_deq, rows, m_ref, l_ref, acc_ref)
 
     @pl.when(c == n_cand - 1)
@@ -256,10 +275,10 @@ def _lop_exact_kernel(nl_ref, po_ref, id_ref, gate_ref, qi_ref, qs_ref,
 # Fused dense kernel body (no-LOP baseline on the same grid layout)
 # ---------------------------------------------------------------------------
 
-def _fused_dense_kernel(nl_ref, po_ref, qi_ref, qs_ref, k_ref, v_ref,
-                        ks_ref, vs_ref, o_ref, *rest, nb, hkv, block,
-                        window, softmax_scale, return_stats):
+def _fused_dense_kernel(nl_ref, po_ref, qi_ref, qs_ref, *refs, nb, hkv,
+                        block, window, softmax_scale, return_stats, v_cols):
     """Grid (b·hkv, NB): exact attention streamed over every K/V block."""
+    k_ref, v_ref, ks_ref, vs_ref, (o_ref, *rest) = _kv_refs(refs, v_cols)
     if return_stats:
         m_out, l_out = rest[0], rest[1]
         rest = rest[2:]
@@ -291,7 +310,7 @@ def _fused_dense_kernel(nl_ref, po_ref, qi_ref, qs_ref, k_ref, v_ref,
             preferred_element_type=jnp.int32).astype(jnp.float32)
         s = s * qs_ref[0] * ks_ref[0].reshape(1, block) * softmax_scale
         s = jnp.where(tvalid0, s, NEG_INF)
-        v_deq = v_ref[0].astype(jnp.float32) * vs_ref[0]
+        v_deq = _values(k_ref, v_ref, ks_ref, vs_ref, v_cols)
         _online_update(s, v_deq, slice(None), m_ref, l_ref, acc_ref)
 
     @pl.when(j == nb - 1)
@@ -303,27 +322,18 @@ def _fused_dense_kernel(nl_ref, po_ref, qi_ref, qs_ref, k_ref, v_ref,
 # Entry
 # ---------------------------------------------------------------------------
 
-@contextlib.contextmanager
-def _phase(name: str):
-    """Name the ops of one LOP phase ``name``: in their op names
-    (``jax.named_scope``) and, as the frontend attribute ``scope``, in
-    the instruction text a TPU profiler trace gives each device op.
-    Pallas overwrites a launch's frontend attributes with its kernel
-    metadata, so a launch takes the yielded mark as ``metadata=``."""
-    with jax.named_scope(name), set_xla_metadata(scope=name):
-        yield {"scope": name}
-
-
 @functools.partial(jax.jit, static_argnames=(
     "hkv", "block", "k_keep", "window", "softmax_scale", "use_lop",
-    "shared_select", "return_stats", "n_buckets", "n_slots", "interpret"))
+    "shared_select", "return_stats", "n_buckets", "n_slots", "v_cols",
+    "marks", "interpret"))
 def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
                            feat, new_len, pos_off, *, hkv: int, block: int,
                            k_keep: int, window: int, softmax_scale: float,
                            use_lop: bool = True, shared_select: bool = False,
                            return_stats: bool = False,
                            n_buckets: int = DEFAULT_N_BUCKETS,
-                           n_slots: int = 2,
+                           n_slots: int = 2, v_cols: int = 0,
+                           marks: tuple = (),
                            interpret: bool = False):
     """One fused decode-attention step over every (batch, kv-head) lane.
 
@@ -338,9 +348,15 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
     n_slots   candidate K/V buffers in VMEM: 2 = double buffering, the
               default; 1 fetches each block after the previous fold.
               Mosaic pipelines no deeper. Bitwise either way.
-    → f32 [BH, G, d]; with ``return_stats`` also (m, ℓ) f32 [BH, G, 1].
+    v_cols    > 0: values are the key rows' first v_cols columns (v_cache
+              and v_scale None; module docstring)
+    marks     the ``repro.core.marks`` scopes the caller traces this in
+              (a compile key: the launches carry them)
+    → f32 [BH, G, dv] (dv = v_cols or d); with ``return_stats`` also
+    (m, ℓ) f32 [BH, G, 1].
     """
     bhg, g, d = qi.shape
+    dv = v_cols or d
     m = k_cache.shape[1]
     assert m % block == 0, (m, block)
     assert n_slots in (1, 2), n_slots
@@ -350,16 +366,24 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
 
     # index maps take (grid indices, *scalar-prefetch refs)
     lane = lambda bh, j, *_: (bh, 0, 0)
-    outs = [jax.ShapeDtypeStruct((bhg, g, d), jnp.float32)]
-    out_specs = [pl.BlockSpec((1, g, d), lane)]
+    outs = [jax.ShapeDtypeStruct((bhg, g, dv), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, g, dv), lane)]
     if return_stats:
         outs += [jax.ShapeDtypeStruct((bhg, g, 1), jnp.float32)] * 2
         out_specs += [pl.BlockSpec((1, g, 1), lane)] * 2
     softmax_scratch = [
         pltpu.VMEM((g, 128), jnp.float32),       # running max
         pltpu.VMEM((g, 128), jnp.float32),       # running sum-exp
-        pltpu.VMEM((g, d), jnp.float32),         # output accumulator
+        pltpu.VMEM((g, dv), jnp.float32),        # output accumulator
     ]
+    # K (and V, unless it is K's first columns) operands and their specs
+    kv_ops = ((k_cache, k_scale) if v_cols
+              else (k_cache, v_cache, k_scale, v_scale))
+
+    def kv_specs(index, **kw):
+        rows = (pl.BlockSpec((1, block, d), index, **kw),)
+        scales = (pl.BlockSpec((1, block, 1), index, **kw),)
+        return list(rows + scales if v_cols else 2 * rows + 2 * scales)
 
     if not use_lop:
         block_j = lambda bh, j, *_: (bh, j, 0)
@@ -369,11 +393,7 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
             in_specs=[
                 pl.BlockSpec((1, g, d), lane),
                 pl.BlockSpec((1, g, 1), lane),
-                pl.BlockSpec((1, block, d), block_j),
-                pl.BlockSpec((1, block, d), block_j),
-                pl.BlockSpec((1, block, 1), block_j),
-                pl.BlockSpec((1, block, 1), block_j),
-            ],
+            ] + kv_specs(block_j),
             out_specs=out_specs if return_stats else out_specs[0],
             scratch_shapes=softmax_scratch,
         )
@@ -381,17 +401,18 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
             functools.partial(_fused_dense_kernel, nb=nb, hkv=hkv,
                               block=block, window=window,
                               softmax_scale=softmax_scale,
-                              return_stats=return_stats),
+                              return_stats=return_stats, v_cols=v_cols),
             grid_spec=grid_spec,
             out_shape=outs if return_stats else outs[0],
             interpret=interpret,
-        )(new_len, pos_off, qi, qsc, k_cache, v_cache, k_scale, v_scale)
+            metadata=dict(marks) or None,
+        )(new_len, pos_off, qi, qsc, *kv_ops)
         return out
 
     # A custom call takes its HLO instruction name from the innermost
     # scope around it: each launch names itself ``fused_decode_attention``
     # so its instruction keeps the name the dense launch has.
-    with _phase("lop_screen") as mark:
+    with scope("lop_screen") as mark:
         qp = pot(qi)
         half = pl.BlockSpec((1, g, d // 2), lane)
         rank = pl.pallas_call(
@@ -425,33 +446,24 @@ def fused_decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale,
     n_cand = k_keep * g_sel
     cand = lambda bh, c, nl, po, ids_, gate_: (bh, ids_[bh * n_cand + c], 0)
     buffered = pl.Buffered(n_slots)
-    with _phase("lop_exact") as mark:
+    with scope("lop_exact") as mark:
         return pl.pallas_call(
             functools.partial(_lop_exact_kernel, g=g, n_cand=n_cand,
                               hkv=hkv, block=block, k_keep=k_keep,
                               window=window, softmax_scale=softmax_scale,
                               shared_select=shared_select,
-                              return_stats=return_stats),
+                              return_stats=return_stats, v_cols=v_cols),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=4,
                 grid=(bhg, n_cand),
                 in_specs=[
                     pl.BlockSpec((1, g, d), lane),
                     pl.BlockSpec((1, g, 1), lane),
-                    pl.BlockSpec((1, block, d), cand,
-                                 pipeline_mode=buffered),
-                    pl.BlockSpec((1, block, d), cand,
-                                 pipeline_mode=buffered),
-                    pl.BlockSpec((1, block, 1), cand,
-                                 pipeline_mode=buffered),
-                    pl.BlockSpec((1, block, 1), cand,
-                                 pipeline_mode=buffered),
-                ],
+                ] + kv_specs(cand, pipeline_mode=buffered),
                 out_specs=out_specs if return_stats else out_specs[0],
                 scratch_shapes=softmax_scratch,
             ),
             out_shape=outs if return_stats else outs[0],
             interpret=interpret,
             name="fused_decode_attention", metadata=mark,
-        )(new_len, pos_off, ids, gate, qi, qsc, k_cache, v_cache, k_scale,
-          v_scale)
+        )(new_len, pos_off, ids, gate, qi, qsc, *kv_ops)

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lop import pot
+from repro.core.marks import launch_metadata
 from repro.core.quantization import rmsnorm as rmsnorm_ref
 from repro.core.ternary import TernaryWeight
 from repro.kernels import autotune as _tune
@@ -212,7 +213,8 @@ def qlinear_fused(x: jax.Array, packed: jax.Array, scale: jax.Array,
     out = _ql.fused_qlinear(x3, p3, s3, b3, bm=bm, bn=bn,
                             bkq=tuned.get("bkq", 0),
                             eg=tuned.get("eg", 1),
-                            act=act, interpret=_interpret())[:, :m0]
+                            act=act, metadata=launch_metadata(),
+                            interpret=_interpret())[:, :m0]
     if expert:
         return out
     return out.reshape(*x.shape[:-1], n)
@@ -261,6 +263,7 @@ def ffn_fused(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
         x3 = jnp.pad(x3, ((0, 0), (0, pad), (0, 0)))
     out = _ql.fused_ffn(x3, gu3, gs3, d3, ds3, bm=bm, bf=bf, bn=bn,
                         bkq=tuned.get("bkq", 0), act=act, gated=gated,
+                        metadata=launch_metadata(),
                         interpret=_interpret())[:, :m0]
     if expert:
         return out
@@ -341,6 +344,22 @@ def sparse_decode(q, k_cache, v_cache, q_scale, k_scale, v_scale,
 # Fused batched prefill attention — THE prefill entry point
 # ---------------------------------------------------------------------------
 
+# resident query-row state of one prefill-attention grid step, bq·(256 +
+# d) f32 (q, scales, softmax state, accumulator): above this the rows
+# tile into slabs, bitwise the untiled kernel
+PREFILL_ROWS_VMEM = 4 << 20
+
+
+def _prefill_row_block(r: int, d: int) -> int:
+    """Default query-row slab of the prefill kernel: 0 (all R rows) where
+    they fit ``PREFILL_ROWS_VMEM``, else the largest halving of R that
+    does and stays a multiple of the 8-row sublane tile."""
+    bq = r
+    while bq * (256 + d) * 4 > PREFILL_ROWS_VMEM and bq % 16 == 0:
+        bq //= 2
+    return 0 if bq == r else bq
+
+
 def prefill_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, kv_len, *,
                       q_offset=None, causal: bool = True, window: int = 0,
                       softmax_scale: float | None = None,
@@ -399,13 +418,14 @@ def prefill_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, kv_len, *,
 
     # flatten (B, Hkv) → the kernel's batched lane axis; rows g-major
     bh = b * hkv
+    bq = tuned.get("bq", _prefill_row_block(g * c, dh))
     qig = qi.reshape(b, hkv, g, c, dh).reshape(bh, g * c, dh)
     qsg = qsc.reshape(b, hkv, g, c).reshape(bh, g * c, 1)
     po = jnp.full((1,), 0 if q_offset is None else q_offset, jnp.int32)
     out = _pf.fused_prefill_attention(
         qig, qsg, k_cache.reshape(bh, m, dh), v_cache.reshape(bh, m, dh),
         k_scale.reshape(bh, m, 1), v_scale.reshape(bh, m, 1), kv_len, po,
-        hkv=hkv, chunk=c, block=bk, bq=tuned.get("bq", 0), causal=causal,
+        hkv=hkv, chunk=c, block=bk, bq=bq, causal=causal,
         window=window, softmax_scale=softmax_scale,
         int8_logits=int8_logits, interpret=_interpret())
     return out.reshape(b, h, c, dh)
@@ -420,7 +440,7 @@ def decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, feat,
                      softmax_scale: float | None = None,
                      use_lop: bool = True, shared_select: bool = False,
                      pos_offset=None, return_stats: bool = False,
-                     impl: str = "auto"):
+                     v_cols: int = 0, impl: str = "auto"):
     """Single entry for every decode-attention flavour (DESIGN.md
     §Fused-decode-kernel).
 
@@ -440,6 +460,9 @@ def decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, feat,
                    shard offset; must be a multiple of ``block``)
     return_stats   also return the unnormalized softmax stats (m, ℓ)
                    f32 [B, H, 1] for the flash-decoding shard merge
+    v_cols         > 0: the values are the first ``v_cols`` columns of
+                   the key rows, with the key scales (latent attention;
+                   ``v_cache``/``v_scale`` None) → f32 [B, H, v_cols]
 
     → f32 [B, H, dh]  (or ``(out, m, ℓ)`` with ``return_stats``).
 
@@ -460,6 +483,8 @@ def decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, feat,
                              "block": block, "k_keep": k_keep})
 
     if _resolve(impl) == "ref":
+        if v_cols:
+            v_cache, v_scale = k_cache[..., :v_cols], k_scale
         return _ref.decode_attention_ref(
             qi, qsc, k_cache, v_cache, k_scale, v_scale, feat, new_len,
             block=block, k_keep=k_keep, window=window,
@@ -472,9 +497,11 @@ def decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, feat,
     qig = qi.reshape(b, hkv, g, dh).reshape(bh, g, dh)
     qsg = qsc.reshape(b, hkv, g, 1).reshape(bh, g, 1)
     kf = k_cache.reshape(bh, m, dh)
-    vf = v_cache.reshape(bh, m, dh)
     ksf = k_scale.reshape(bh, m, 1)
-    vsf = v_scale.reshape(bh, m, 1)
+    vf = vsf = None
+    if not v_cols:
+        vf = v_cache.reshape(bh, m, dh)
+        vsf = v_scale.reshape(bh, m, 1)
     featf = feat.reshape(bh, m, dh // 2)
     po = jnp.full((1,), 0 if pos_offset is None else pos_offset, jnp.int32)
     tuned = _tune.lookup("decode", {"bhg": bh, "g": g, "d": dh, "m": m,
@@ -484,9 +511,11 @@ def decode_attention(qi, qsc, k_cache, v_cache, k_scale, v_scale, feat,
         hkv=hkv, block=block, k_keep=k_keep, window=window,
         softmax_scale=softmax_scale, use_lop=use_lop,
         shared_select=shared_select, return_stats=return_stats,
-        n_slots=tuned.get("n_slots", 2), interpret=_interpret())
+        n_slots=tuned.get("n_slots", 2), v_cols=v_cols,
+        marks=launch_metadata(), interpret=_interpret())
+    dv = v_cols or dh
     if return_stats:
         o, ms, ls = out
-        return (o.reshape(b, h, dh), ms.reshape(b, h, 1),
+        return (o.reshape(b, h, dv), ms.reshape(b, h, 1),
                 ls.reshape(b, h, 1))
-    return out.reshape(b, h, dh)
+    return out.reshape(b, h, dv)

@@ -157,11 +157,11 @@ def _qlinear_kernel(x_ref, wp_ref, sc_ref, *rest, k, bkq, nk, eg, act,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bkq", "eg", "act",
-                                             "interpret"))
+                                             "metadata", "interpret"))
 def fused_qlinear(x: jax.Array, packed: jax.Array, scale: jax.Array,
                   bias: jax.Array | None = None, *, bm: int,
                   bn: int = DEFAULT_BN, bkq: int = 0, eg: int = 1,
-                  act: str | None = None,
+                  act: str | None = None, metadata: tuple = (),
                   interpret: bool = False) -> jax.Array:
     """f32 x [E, m, k] × packed ternary [E, k//4, n] → f32 [E, m, n].
 
@@ -176,7 +176,8 @@ def fused_qlinear(x: jax.Array, packed: jax.Array, scale: jax.Array,
     k-tiled variant — the f32 activation enters VMEM in [bm, bkq] tiles
     only; ``eg`` (a divisor of E) groups that many experts per grid
     step. Both are pure tiling knobs (DESIGN.md §Autotuning): any legal
-    setting is bitwise ``bkq=0, eg=1``.
+    setting is bitwise ``bkq=0, eg=1``. ``metadata``: the launch's trace
+    marks (``repro.core.marks.launch_metadata``).
     """
     e, m, k = x.shape
     n = packed.shape[-1]
@@ -230,6 +231,7 @@ def fused_qlinear(x: jax.Array, packed: jax.Array, scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        metadata=dict(metadata) or None,
         **kwargs,
     )(*operands)
 
@@ -290,10 +292,12 @@ def _ffn_kernel(x_ref, up_ref, usc_ref, *rest, k, f, bf, bkq, nk, nf, nd,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "bn", "bkq", "act",
-                                             "gated", "interpret"))
+                                             "gated", "metadata",
+                                             "interpret"))
 def fused_ffn(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
               down_packed: jax.Array, down_scale: jax.Array, *, bm: int,
               bf: int, bn: int, bkq: int = 0, act: str, gated: bool,
+              metadata: tuple = (),
               interpret: bool = False) -> jax.Array:
     """The whole FFN as one launch: x [E, m, k] → f32 [E, m, d_out].
 
@@ -308,7 +312,8 @@ def fused_ffn(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
     barrier prefix when ``bkq`` > 0 (bitwise ``bkq=0``; the *hidden*
     barrier stays single-pass — its [bm, f] scratch lives in VMEM
     either way). The hidden scratch never leaves VMEM; its absmax
-    barrier runs at the first down step.
+    barrier runs at the first down step. ``metadata``: the launch's
+    trace marks (``repro.core.marks.launch_metadata``).
     """
     e, m, k = x.shape
     f = down_packed.shape[-2] * 4
@@ -377,5 +382,6 @@ def fused_ffn(x: jax.Array, gu_packed: jax.Array, gu_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((e, m, d_out), jnp.float32),
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        metadata=dict(metadata) or None,
         **kwargs,
     )(*operands)

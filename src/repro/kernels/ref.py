@@ -259,7 +259,7 @@ def _gather_blocks(arr, idx, block):
 
 
 def _stats_to_out(m, l, acc, b, h, dh, return_stats):
-    out = (acc / jnp.where(l > 0, l, 1.0)).reshape(b, h, dh)
+    out = (acc / jnp.where(l > 0, l, 1.0)).reshape(b, h, acc.shape[-1])
     if return_stats:
         return out, m.reshape(b, h, 1), l.reshape(b, h, 1)
     return out

@@ -40,6 +40,7 @@ _REDUCED_MODULES = {
     "qwen1.5-110b": "qwen1_5_110b",
     "rwkv6-1.6b": "rwkv6_1_6b",
     "bitnet-3b": "bitnet_3b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

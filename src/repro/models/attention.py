@@ -59,6 +59,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     import os
     b, sq, h, dh = q.shape
     skv, hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     if softmax_scale is None:
         softmax_scale = dh ** -0.5
     # accounting probes raise the chunk so unrolling stays tractable —
@@ -105,7 +106,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     from repro.models.scan_utils import accounting_unroll
     _, oc = jax.lax.scan(body, None, (qc, jnp.arange(nc)),
                          unroll=accounting_unroll(nc))
-    o = oc.transpose(1, 0, 2, 3, 4).reshape(b, nc * chunk, h, dh)
+    o = oc.transpose(1, 0, 2, 3, 4).reshape(b, nc * chunk, h, dv)
     return o[:, :sq]
 
 
@@ -114,6 +115,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
 # ---------------------------------------------------------------------------
 
 def attention_init(key, cfg):
+    if cfg.mla:
+        return mla_init(key, cfg)
     keys = jax.random.split(key, 4)
     d = cfg.d_model
     p, s = {}, {}
@@ -142,6 +145,9 @@ def attention_apply(cfg, p, x, *, kv_x=None, causal=True, positions=None,
     original invariant: plain cross-attention (non-causal, no rope,
     no window), whatever ``use_rope`` says.
     """
+    if cfg.mla:
+        raise NotImplementedError("latent attention runs on the served "
+                                  "path only (serving/engine.py)")
     b, sq, _ = x.shape
     src = x if kv_x is None else kv_x
     skv = src.shape[1]
@@ -170,3 +176,44 @@ def attention_apply(cfg, p, x, *, kv_x=None, causal=True, positions=None,
                           q_offset=q_offset)
     o = o.reshape(b, sq, cfg.q_dim)
     return linear_apply(p["wo"], o, quant=cfg.quant)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+
+def mla_init(key, cfg):
+    """q_proj; kv_a (the latent c ‖ the shared k_rope) and its RMSNorm on
+    c; kv_b (c → per head k_nope ‖ v); o_proj. No q-LoRA."""
+    keys = jax.random.split(key, 4)
+    d, h = cfg.d_model, cfg.n_heads
+    p, s = {}, {}
+    p["wq"], s["wq"] = linear_init(keys[0], d, cfg.mla_q_dim)
+    p["wkv_a"], s["wkv_a"] = linear_init(keys[1], d, cfg.latent_dim)
+    p["kv_norm"], s["kv_norm"] = ({"g": jnp.ones((cfg.kv_lora_rank,),
+                                                 jnp.float32)},
+                                  {"g": (None,)})
+    p["wkv_b"], s["wkv_b"] = linear_init(
+        keys[2], cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim
+                                        + cfg.v_head_dim))
+    p["wo"], s["wo"] = linear_init(keys[3], h * cfg.v_head_dim, d,
+                                   spec=("tp", "fsdp"))
+    return p, s
+
+
+def mla_attend(q_nope, q_rope, c, k_rope, w_kb, *, softmax_scale: float,
+               causal: bool = True):
+    """The published (non-absorbed) latent attention core, float.
+
+    q_nope [B, S, H, dn]; q_rope [B, S, H, dr] (rotated); c [B, S, dc]
+    (normed latent); k_rope [B, S, dr] (rotated, shared by the heads);
+    w_kb [dc, H·(dn+dv)] (kv_b, per head k_nope ‖ v) → [B, S, H, dv].
+    Keys are k_nope = c·W_UK ‖ k_rope, values v = c·W_UV."""
+    b, s, h, dn = q_nope.shape
+    kv = (c @ w_kb).reshape(b, s, h, -1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)],
+        -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    return chunked_attention(q, k, kv[..., dn:], causal=causal,
+                             softmax_scale=softmax_scale)

@@ -11,6 +11,8 @@ norms, router and the LM head stay high-precision (BitNet's convention).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -98,11 +100,16 @@ def head_apply(params, x):
 # Rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x [..., S, H, dh] (dh even), positions [..., S] → rotated x."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         freqs=None) -> jax.Array:
+    """x [..., S, H, dh] (dh even), positions [..., S] → rotated x.
+
+    ``freqs`` [dh/2] replaces the plain ``theta`` frequencies (YaRN:
+    :func:`yarn_freqs`)."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs      # [..., S, half]
     cos = jnp.cos(ang)[..., None, :]                            # [..., S, 1, half]
     sin = jnp.sin(ang)[..., None, :]
@@ -111,3 +118,60 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     out = jnp.concatenate([xf1 * cos - xf2 * sin,
                            xf2 * cos + xf1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# YaRN (DeepSeek-V2's rotary scaling, arXiv:2309.00071)
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_max_pos: int,
+               beta_fast: float, beta_slow: float):
+    """Rotary frequencies [dim/2] f32: the plain ``theta`` frequencies
+    where a dimension turns more than ``beta_fast`` times over the
+    original context, those divided by ``factor`` where it turns fewer
+    than ``beta_slow`` times, and a linear ramp between."""
+    import numpy as np
+
+    def corr_dim(rot):
+        return (dim * math.log(original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    exps = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = 1.0 / (theta ** exps)
+    inter = 1.0 / (factor * theta ** exps)
+    mask = 1.0 - ramp
+    return (inter * (1 - mask) + extra * mask).astype(np.float32)
+
+
+def rope_freqs(cfg):
+    """The latent-attention rotary frequencies of ``cfg`` (YaRN where
+    ``rope_factor`` is set), or None for the plain ``rope_theta`` ones."""
+    if not cfg.rope_factor:
+        return None
+    if yarn_mscale(cfg.rope_factor, cfg.rope_mscale) != yarn_mscale(
+            cfg.rope_factor, cfg.rope_mscale_all_dim):
+        raise ValueError("YaRN with cos/sin scaled (mscale != "
+                         "mscale_all_dim) is not implemented")
+    return jnp.asarray(yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                  cfg.rope_factor, cfg.rope_original_max_pos,
+                                  cfg.rope_beta_fast, cfg.rope_beta_slow))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(qk_nope + qk_rope)^-0.5, times mscale² under YaRN with
+    ``rope_mscale_all_dim`` (DeepSeek-V2: 0.11472)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_factor and cfg.rope_mscale_all_dim:
+        m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+        scale *= m * m
+    return scale

@@ -1,11 +1,20 @@
 """FFN and token-choice top-k Mixture-of-Experts.
 
-MoE dispatch is capacity-bucketed einsum dispatch over token *groups*
-(scanned), so the one-hot dispatch tensor stays ``[group, E, capacity]`` —
-small and transient — instead of ``[tokens, E, capacity]``. Expert weights
-are sharded TP-inside-expert (``[E, d, ff]`` with ff on the model axis),
-which divides for every assigned expert count; a shard_map all-to-all EP
-variant lives in :mod:`repro.distributed.expert_parallel`.
+Training dispatch is capacity-bucketed einsum dispatch over token
+*groups* (scanned), so the one-hot dispatch tensor stays ``[group, E,
+capacity]`` — small and transient — instead of ``[tokens, E, capacity]``.
+Expert weights are sharded TP-inside-expert (``[E, d, ff]`` with ff on
+the model axis), which divides for every assigned expert count; a
+shard_map all-to-all EP variant lives in
+:mod:`repro.distributed.expert_parallel`.
+
+The served path (packed expert weights) is *dropless* instead
+(:func:`moe_serve`, DESIGN.md §Latent-attention-and-dropless-experts): every expert's
+capacity is the call's row count, so no token is dropped and a token's
+output depends on its own row alone — a prompt prefilled whole, in
+chunks, or decoded beside any other lanes routes and computes the same.
+Shared experts (DeepSeek) run as one always-on FFN beside the routed
+ones.
 
 All expert projections are BitLinear under the ternary flow (BitNet applies
 to every weight projection — MoE experts included); the router stays f32.
@@ -18,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core.qlinear import (ffn_node_apply, is_fused_ffn, is_packed,
                                 qlinear_expert)
+from repro.core.marks import scope
 from repro.core.ternary import ste_ternary
 from repro.distributed.partitioning import shard
 from repro.models.layers import linear_apply, linear_init
@@ -27,9 +37,9 @@ from repro.models.layers import linear_apply, linear_init
 # Dense FFN (gated silu, or plain gelu for whisper)
 # ---------------------------------------------------------------------------
 
-def ffn_init(key, cfg):
+def ffn_init(key, cfg, d_ff: int | None = None):
     keys = jax.random.split(key, 3)
-    d, f = cfg.d_model, cfg.d_ff
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     p, s = {}, {}
     if cfg.gated_ffn:
         p["w_gate"], s["w_gate"] = linear_init(keys[0], d, f)
@@ -61,7 +71,7 @@ def ffn_apply(cfg, p, x):
 
 def moe_init(key, cfg):
     keys = jax.random.split(key, 4)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
     scale = d ** -0.5
     p = {
         "router": jax.random.normal(keys[0], (d, e), jnp.float32) * scale,
@@ -76,6 +86,10 @@ def moe_init(key, cfg):
         "w_up": (None, "fsdp", "tp"),
         "w_down": (None, "tp", "fsdp"),
     }
+    if cfg.n_shared_experts:
+        p["shared"], s["shared"] = ffn_init(
+            jax.random.fold_in(key, 4), cfg,
+            cfg.n_shared_experts * cfg.expert_width)
     return p, s
 
 
@@ -117,6 +131,22 @@ def _prepare_expert_weights(cfg, p, act_dtype):
     return p
 
 
+def _expert_ffn(cfg, p, xe):
+    """Every expert's FFN over its rows xe [E, C, d] → [E, C, d]."""
+    if is_fused_ffn(p):
+        # serving format: every expert's gate·up → barrier → down runs in
+        # ONE grouped dispatch (expert = grid axis of the fused kernel)
+        return ffn_node_apply(p, xe, gated=cfg.gated_ffn,
+                              act="silu" if cfg.gated_ffn else "gelu")
+    if cfg.gated_ffn:
+        h = jax.nn.silu(_expert_linear(p["w_gate"], xe, cfg.quant))
+        h = h * _expert_linear(p["w_up"], xe, cfg.quant)
+    else:
+        h = jax.nn.gelu(_expert_linear(p["w_up"], xe, cfg.quant))
+    h = shard(h, None, None, "tp")
+    return _expert_linear(p["w_down"], h, cfg.quant)
+
+
 def _dispatch_group(cfg, p, x):
     """One token group [T, d] → MoE output [T, d] + aux losses."""
     t, d = x.shape
@@ -127,8 +157,9 @@ def _dispatch_group(cfg, p, x):
     logits = x.astype(jnp.float32) @ p["router"]                  # [T, E]
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)                 # [T, k]
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
     # position of each (token, choice) within its expert's capacity bucket
     choice_mask = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)    # [T, k, E]
@@ -147,19 +178,7 @@ def _dispatch_group(cfg, p, x):
         * keep[..., None, None].astype(x.dtype), axis=1)          # weighted
 
     xe = jnp.einsum("tec,td->ecd", disp, x)                       # [E, cap, d]
-    if is_fused_ffn(p):
-        # serving format: every expert's gate·up → barrier → down runs in
-        # ONE grouped dispatch (expert = grid axis of the fused kernel)
-        ye = ffn_node_apply(p, xe, gated=cfg.gated_ffn,
-                            act="silu" if cfg.gated_ffn else "gelu")
-    else:
-        if cfg.gated_ffn:
-            h = jax.nn.silu(_expert_linear(p["w_gate"], xe, cfg.quant))
-            h = h * _expert_linear(p["w_up"], xe, cfg.quant)
-        else:
-            h = jax.nn.gelu(_expert_linear(p["w_up"], xe, cfg.quant))
-        h = shard(h, None, None, "tp")
-        ye = _expert_linear(p["w_down"], h, cfg.quant)            # [E, cap, d]
+    ye = _expert_ffn(cfg, p, xe)                                  # [E, cap, d]
     y = jnp.einsum("tec,ecd->td", comb, ye)
 
     # load-balancing aux loss (Switch-style)
@@ -182,6 +201,8 @@ def moe_apply(cfg, p, x):
     from repro.distributed.partitioning import current_mesh, dp_axes
 
     b, s, d = x.shape
+    if is_fused_ffn(p) or is_packed(p["w_up"]):
+        return moe_serve(cfg, p, x), jnp.float32(0)
     p = _prepare_expert_weights(cfg, p, x.dtype)
     flat = x.reshape(b * s, d)
     t = flat.shape[0]
@@ -216,4 +237,56 @@ def moe_apply(cfg, p, x):
                                  unroll=accounting_unroll(steps))
     y = ys.transpose(1, 0, 2, 3).reshape(dp, t_loc, d)
     y = y[:, : t // dp].reshape(t, d)
-    return y.reshape(b, s, d), jnp.mean(auxs)
+    y = y.reshape(b, s, d)
+    return y, jnp.mean(auxs)
+
+
+def moe_serve(cfg, p, x):
+    """Dropless MoE over packed expert weights: x [B, S, d] → [B, S, d].
+
+    Each of the T = B·S rows picks its top-k experts by the router's
+    softmax (the router matmul at HIGHEST precision on fixed row tiles,
+    so a row's routing never depends on the other rows in the call).
+    Expert e's bucket holds T rows — top-k experts are distinct, so no
+    expert can receive more and no token is dropped; a routed row is
+    copied into its slot by a scatter and its output read back by a
+    gather (exact moves, no one-hot product). Every expert's FFN runs
+    over its bucket in one grouped dispatch, each row behind its own
+    absmax barrier, and the k outputs combine weighted by the gates in a
+    fixed order. Shared experts are one FFN over every row. This form
+    computes E·T expert rows, the empty slots included.
+    """
+    from repro.kernels import ops
+
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.top_k
+    flat = x.reshape(b * s, d).astype(jnp.float32)
+    t = flat.shape[0]
+    with scope("moe_route"):
+        router = p["router"].astype(jnp.float32)
+        probs = ops.map_row_tiles(
+            lambda r: jax.nn.softmax(jnp.dot(
+                r, router, precision=jax.lax.Precision.HIGHEST), axis=-1),
+            flat)                                             # [T, E]
+        gate, idx = jax.lax.top_k(probs, k)                   # [T, k]
+        if cfg.norm_topk_prob:
+            total = gate[:, 0]
+            for j in range(1, k):
+                total = total + gate[:, j]
+            gate = gate / jnp.maximum(total, 1e-9)[:, None]
+        # slot of (row, choice) in its expert's bucket: rows in order
+        hits = (idx[..., None] == jnp.arange(e)).astype(jnp.int32)
+        before = jnp.cumsum(hits.reshape(t * k, e), axis=0).reshape(
+            t, k, e) - hits
+        pos = jnp.sum(before * hits, axis=-1)                 # [T, k]
+        xe = jnp.zeros((e, t, d), jnp.float32).at[idx, pos].set(
+            jnp.broadcast_to(flat[:, None], (t, k, d)), unique_indices=True)
+    with scope("moe_experts"):
+        ye = _expert_ffn(cfg, p, xe)                          # [E, T, d]
+        out = ye[idx, pos]                                    # [T, k, d]
+        y = gate[:, 0, None] * out[:, 0]
+        for j in range(1, k):
+            y = y + gate[:, j, None] * out[:, j]
+        if "shared" in p:
+            y = y + ffn_apply(cfg, p["shared"], flat)
+    return y.reshape(b, s, d).astype(x.dtype)

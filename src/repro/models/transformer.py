@@ -175,9 +175,15 @@ def stacked_groups(cfg, key):
     group key into one key per layer."""
     keys = jax.random.split(key, 8)
     if cfg.family in ("dense", "moe", "vlm"):
-        return {"layers": (functools.partial(
+        groups = {"layers": (functools.partial(
             decoder_layer_init, cfg=cfg, moe=cfg.family == "moe"),
-            keys[2], cfg.n_layers)}
+            keys[2], cfg.n_layers - cfg.first_k_dense)}
+        if cfg.first_k_dense:
+            # leading dense layers: a stack of their own (DeepSeek)
+            groups = {"dense_layers": (functools.partial(
+                decoder_layer_init, cfg=cfg, moe=False), keys[4],
+                cfg.first_k_dense), **groups}
+        return groups
     if cfg.family == "hybrid":
         assert cfg.n_layers % cfg.attn_every == 0
         return {"blocks": (functools.partial(superblock_init, cfg=cfg),

@@ -239,8 +239,7 @@ class InferenceEngine(Protocol):
                                with split-invariant per-token compute).
       ``exact_length_prefill`` prompts must prefill at their exact
                                length — no pow2 pad buckets (recurrent
-                               state integrates every position, MoE
-                               routers rank per forward call, encdec
+                               state integrates every position, encdec
                                compiles against its encoder frames).
       ``has_image_prefix``     requests may carry ``patches`` that
                                occupy cache positions before the text.
@@ -370,18 +369,21 @@ class PooledEngine:
             else cfg.n_layers // 2)))
         self.draft_k = 1 if draft_k is None else max(1, draft_k)
         # ---- capability declarations (family → behaviour, once) ----
-        self.supports_chunked = cfg.family in ("dense", "vlm")
+        # moe chunks too: its served experts are dropless, so a token's
+        # output never depends on the rows it shares a call with
+        self.supports_chunked = cfg.family in ("dense", "moe", "vlm")
         self.exact_length_prefill = cfg.family in ("hybrid", "ssm",
-                                                   "encdec", "moe")
+                                                   "encdec")
         self.has_image_prefix = cfg.family == "vlm"
         self.state_kind = _STATE_KINDS[cfg.family]
         # prefix pages are lop_block-aligned (cache pages already are),
         # and resume rides the chunked (start, kv_len) carry — so prefix
         # caching exists exactly where chunked prefill does
         self.prefix_block = cfg.lop_block if self.supports_chunked else 0
-        # speculation needs rewindable positional state AND a chunk-shaped
-        # verify launch — exactly the chunked-prefill families
-        self.supports_speculative = self.supports_chunked
+        # speculation needs rewindable positional state, a chunk-shaped
+        # verify launch AND a uniform layer stack to truncate for the
+        # draft (engine.draft_step): dense and vlm
+        self.supports_speculative = cfg.family in ("dense", "vlm")
 
         self.prefill_compiles = 0
         self._fns: dict = {}

@@ -5,6 +5,13 @@ int8 (absmax barrier), plus the 4-bit (sgn‖LO) *feature cache* the LOP screen
 reads instead of the exact keys — the screen touches M·d/2 bytes while exact
 attention touches only the K selected candidate blocks.
 
+Latent attention (``cfg.mla``) caches one int8 row c ‖ k_rope per token
+(``k``, one lane shared by every head) with its absmax scale and its
+packed features — the same leaves as a K cache, and no V: the values
+are the row's first ``kv_lora_rank`` columns. A model with leading
+dense layers (``first_k_dense``) keeps their cache in a stack of its own,
+``dense_layers``, beside ``layers``; every pool op walks both.
+
 Capacity is block-aligned (``lop_block``) so candidate fetches stay
 contiguous. Recurrent families cache their state instead ("KV cache of
 seq_len" = recurrent state for SSM — DESIGN.md §6).
@@ -74,6 +81,13 @@ def round_up(x: int, mult: int) -> int:
 
 
 def attn_cache_zeros(cfg, batch: int, capacity: int):
+    if cfg.mla:
+        d = cfg.latent_dim
+        return {
+            "k": jnp.zeros((batch, 1, capacity, d), jnp.int8),
+            "k_scale": jnp.zeros((batch, 1, capacity), jnp.float32),
+            "feat": jnp.zeros((batch, 1, capacity, d // 2), jnp.uint8),
+        }
     hkv, dh = cfg.n_kv_heads, cfg.hd
     return {
         "k": jnp.zeros((batch, hkv, capacity, dh), jnp.int8),
@@ -99,8 +113,10 @@ def init_cache(cfg, batch: int, max_len: int, *, align: int | None = None):
     cache = {"lengths": jnp.zeros((batch,), jnp.int32)}
 
     if cfg.family in ("dense", "moe", "vlm"):
-        cache["layers"] = _stack(attn_cache_zeros(cfg, batch, cap),
-                                 cfg.n_layers)
+        layer = attn_cache_zeros(cfg, batch, cap)
+        if cfg.first_k_dense:
+            cache["dense_layers"] = _stack(layer, cfg.first_k_dense)
+        cache["layers"] = _stack(layer, cfg.n_layers - cfg.first_k_dense)
     elif cfg.family == "hybrid":
         n_sb = cfg.n_layers // cfg.attn_every
         n_mamba = cfg.attn_every - 1

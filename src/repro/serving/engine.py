@@ -43,6 +43,20 @@ prefill logits) are ``ModelConfig`` fields pinned once per entry call by
 ``REPRO_GQA_SHARED_SELECT`` / ``REPRO_INT8_LOGITS`` env flags remain as
 fallbacks for unset fields.
 
+Latent attention (MLA, ``cfg.mla``) projects each token to one latent
+row c ‖ k_rope, cached int8 with one scale (``k``/``k_scale``/``feat``,
+one lane shared by every head), and attends in the *absorbed* form in
+prefill and decode alike: per head the query q̃ = (q_nope·W_UKᵀ) ‖ q_rope
+scores the latent rows directly, the attention-weighted latent rows'
+c part is the value, and W_UV maps it back per head before o_proj
+(DESIGN.md §Latent-attention-and-dropless-experts). The absorption, the
+attention launches and W_UV are marked ``mla_attention`` in a profiler
+trace (:mod:`repro.core.marks`); o_proj is not.
+
+Leading dense layers (``first_k_dense``) are a stack of their own,
+``dense_layers``, scanned before ``layers`` with their own cache stack,
+so no step slices or re-joins the pool.
+
 Slot-paged decode: when the cache carries a per-lane ``active`` mask (a
 :func:`repro.serving.cache.init_cache_pool` pool), ``serve_step`` decodes
 only the live lanes — inactive lanes are screened out of the LOP selection
@@ -66,16 +80,18 @@ import jax.numpy as jnp
 
 from repro.configs.base import resolve_decode_flags
 from repro.core.lop import lop_features, pack_features
-from repro.core.qlinear import qlinear, qlinear_split
+from repro.core.marks import scope
+from repro.core.qlinear import qlinear, qlinear_expert, qlinear_split
 from repro.core.quantization import quantize
 from repro.distributed.partitioning import current_mesh, shard
 from repro.kernels import ops
 from repro.models import rwkv6
-from repro.models.layers import (embedding_apply, head_apply, norm_apply,
-                                 rope)
+from repro.models.layers import (embedding_apply, head_apply,
+                                 mla_softmax_scale, norm_apply, rope,
+                                 rope_freqs)
 from repro.models.mamba import mamba_decode_step, mamba_forward
 from repro.models.moe import ffn_apply, moe_apply
-from repro.serving.cache import init_cache, round_up
+from repro.serving.cache import attn_cache_zeros, init_cache, round_up
 from repro.serving.lop_select import k_keep_blocks
 
 NEG_INF = -1e30
@@ -86,6 +102,12 @@ def _layer_scan(body, x, xs):
     from repro.models.scan_utils import accounting_unroll
     length = jax.tree.leaves(xs)[0].shape[0]
     return jax.lax.scan(body, x, xs, unroll=accounting_unroll(length))
+
+
+def _stacks(qp) -> list:
+    """The decoder layer stacks in order: the leading dense layers (a
+    stack of their own, ``first_k_dense``) and then ``layers``."""
+    return [n for n in ("dense_layers", "layers") if n in qp]
 
 
 def _q(x, axis=-1):
@@ -165,6 +187,10 @@ def attn_prefill(cfg, lp, h, *, capacity: int):
     bit-identical per query row (DESIGN.md §Chunked-prefill).
     """
     b, s, _ = h.shape
+    if cfg.mla:
+        return mla_prefill_chunk(cfg, lp, h,
+                                 attn_cache_zeros(cfg, b, capacity),
+                                 start=0, kv_len=jnp.full((b,), s, jnp.int32))
     q, k, v = _project_qkv(cfg, lp, h)
     positions = jnp.arange(s)[None, :]
     q = rope(q, positions, cfg.rope_theta)
@@ -180,12 +206,12 @@ def attn_prefill(cfg, lp, h, *, capacity: int):
                                                                     capacity),
         "feat": _pad_cache(feat, capacity),
     }
-    # dense/vlm must attend the FULL capacity so chunked rows (which see
-    # the pool lane at capacity) stay bitwise equal; run-to-completion
-    # families (moe/hybrid/encdec self-attn) never chunk, so their
-    # attention view trims to the prompt's block roundup — cost scales
-    # with S, not pool capacity
-    m_att = capacity if cfg.family in ("dense", "vlm") \
+    # the chunked families must attend the FULL capacity so chunked rows
+    # (which see the pool lane at capacity) stay bitwise equal;
+    # run-to-completion families (hybrid/encdec self-attn) never chunk,
+    # so their attention view trims to the prompt's block roundup — cost
+    # scales with S, not pool capacity
+    m_att = capacity if cfg.family in ("dense", "moe", "vlm") \
         else min(capacity, round_up(s, cfg.lop_block))
     o = ops.prefill_attention(
         qi, qsc, cache_l["k"][:, :, :m_att], cache_l["v"][:, :, :m_att],
@@ -197,25 +223,28 @@ def attn_prefill(cfg, lp, h, *, capacity: int):
     return out, cache_l
 
 
-def _write_chunk(cl, ki, vi, ksc, vsc, feat, start):
+def _kv_rows(ki, vi, ksc, vsc, feat):
+    """The cache leaves of quantized K/V rows, by leaf name."""
+    return {"k": ki, "v": vi, "k_scale": ksc, "v_scale": vsc, "feat": feat}
+
+
+def _write_chunk(cl, rows, start):
     """Write a C-token quantized chunk into the cache at [start, start+C).
 
-    One ``dynamic_update_slice`` per leaf at the (possibly traced) chunk
-    start — the cache-pool analogue of the per-token ``_write_token``.
-    Padded tail tokens of a final chunk land here too; they sit above
-    ``lengths`` and are stale-masked like every other dead byte
-    (DESIGN.md §Chunked-prefill partial-insert invariants).
+    ``rows`` holds each leaf's C new rows in the cache layout ([B, Hkv,
+    C, ...]). One ``dynamic_update_slice`` per leaf at the (possibly
+    traced) chunk start — the cache-pool analogue of the per-token
+    ``_write_token``. Padded tail tokens of a final chunk land here too;
+    they sit above ``lengths`` and are stale-masked like every other
+    dead byte (DESIGN.md §Chunked-prefill partial-insert invariants).
     """
     def wr(arr, val):
         return jax.lax.dynamic_update_slice(
             arr, val, (0, 0, start) + (0,) * (arr.ndim - 3))
 
     cl = dict(cl)
-    cl["k"] = wr(cl["k"], ki)
-    cl["v"] = wr(cl["v"], vi)
-    cl["feat"] = wr(cl["feat"], feat)
-    cl["k_scale"] = wr(cl["k_scale"], ksc)
-    cl["v_scale"] = wr(cl["v_scale"], vsc)
+    for name, val in rows.items():
+        cl[name] = wr(cl[name], val)
     return cl
 
 
@@ -231,6 +260,8 @@ def attn_prefill_chunk(cfg, lp, h, cl, *, start, kv_len):
     state crosses chunk boundaries (it lives in the kernel's VMEM
     scratch within one call).
     """
+    if cfg.mla:
+        return mla_prefill_chunk(cfg, lp, h, cl, start=start, kv_len=kv_len)
     b, c, _ = h.shape
     q, k, v = _project_qkv(cfg, lp, h)
     positions = start + jnp.arange(c)[None, :]
@@ -241,7 +272,7 @@ def attn_prefill_chunk(cfg, lp, h, cl, *, start, kv_len):
     qi = qi.transpose(0, 2, 1, 3)                        # [B, H, C, dh]
     qsc = qsc[..., 0].transpose(0, 2, 1)
 
-    cl = _write_chunk(cl, ki, vi, ksc, vsc, feat, start)
+    cl = _write_chunk(cl, _kv_rows(ki, vi, ksc, vsc, feat), start)
     o = ops.prefill_attention(
         qi, qsc, cl["k"], cl["v"], cl["k_scale"], cl["v_scale"], kv_len,
         q_offset=start, causal=True, window=cfg.swa_window,
@@ -323,40 +354,35 @@ def lop_decode_attention(cfg, qi, qsc, cl, new_len, *, window: int,
     m = cl["k"].shape[2]
     if k_keep is None:
         k_keep = k_keep_blocks(cfg, m)
+    # latent attention: the values are the latent rows' c part
+    latent = {"v_cols": cfg.kv_lora_rank,
+              "softmax_scale": mla_softmax_scale(cfg)} if cfg.mla else {}
     return ops.decode_attention(
-        qi, qsc, cl["k"], cl["v"], cl["k_scale"], cl["v_scale"], cl["feat"],
-        new_len, block=cfg.lop_block,
+        qi, qsc, cl["k"], cl.get("v"), cl["k_scale"], cl.get("v_scale"),
+        cl["feat"], new_len, block=cfg.lop_block,
         k_keep=max(1, min(k_keep, m // cfg.lop_block)),
         window=window, use_lop=use_lop,
-        shared_select=bool(cfg.gqa_shared_select))
+        shared_select=bool(cfg.gqa_shared_select), **latent)
 
 
-def _write_token(cl, ki, vi, ksc, vsc, feat, lengths, active=None):
+def _write_token(cl, rows, lengths, active=None):
     """Append one quantized token per sequence at its own position.
 
-    With ``active`` given, retired/empty slots keep their lane untouched
-    (the write is computed and discarded — branch-free under vmap).
+    ``rows`` holds each leaf's new row per lane ([B, Hkv, ...]). With
+    ``active`` given, retired/empty slots keep their lane untouched (the
+    write is computed and discarded — branch-free under vmap).
     """
     ok = jnp.ones_like(lengths, bool) if active is None else active
 
     def wr(arr, val, pos, ok_):
-        # arr [Hkv, M, d]; val [Hkv, d]
+        # arr [Hkv, M, ...]; val [Hkv, ...]
         upd = jax.lax.dynamic_update_slice(
             arr, val[:, None], (0, pos) + (0,) * (arr.ndim - 2))
         return jnp.where(ok_, upd, arr)
 
-    def wr_scale(arr, val, pos, ok_):
-        upd = jax.lax.dynamic_update_slice(arr, val[:, None], (0, pos))
-        return jnp.where(ok_, upd, arr)
-
     cl = dict(cl)
-    cl["k"] = jax.vmap(wr)(cl["k"], ki, lengths, ok)
-    cl["v"] = jax.vmap(wr)(cl["v"], vi, lengths, ok)
-    cl["feat"] = jax.vmap(wr)(cl["feat"], feat, lengths, ok)
-    cl["k_scale"] = jax.vmap(wr_scale)(cl["k_scale"], ksc[..., 0], lengths,
-                                       ok)
-    cl["v_scale"] = jax.vmap(wr_scale)(cl["v_scale"], vsc[..., 0], lengths,
-                                       ok)
+    for name, val in rows.items():
+        cl[name] = jax.vmap(wr)(cl[name], val, lengths, ok)
     return cl
 
 
@@ -369,6 +395,12 @@ def attn_decode(cfg, lp, h, cl, lengths, *, use_lop=True, sp_axes=None,
     write, and zero attention output. ``k_keep`` degrades the LOP
     selection budget (speculative draft pass); ``None`` = config policy.
     """
+    if cfg.mla:
+        if sp_axes:
+            raise NotImplementedError("latent attention has no "
+                                      "sequence-sharded decode")
+        return mla_decode(cfg, lp, h, cl, lengths, use_lop=use_lop,
+                          active=active, k_keep=k_keep)
     b = h.shape[0]
     q, k, v = _project_qkv(cfg, lp, h)
     positions = lengths[:, None]
@@ -389,7 +421,8 @@ def attn_decode(cfg, lp, h, cl, lengths, *, use_lop=True, sp_axes=None,
             window=cfg.swa_window, use_lop=use_lop and cfg.use_lop,
             sp_axes=sp_axes, active=active)
     else:
-        cl = _write_token(cl, ki, vi, ksc, vsc, feat, lengths, active)
+        cl = _write_token(cl, _kv_rows(ki, vi, ksc[..., 0], vsc[..., 0],
+                                       feat), lengths, active)
         out = lop_decode_attention(cfg, qi, qsc, cl, new_len,
                                    window=cfg.swa_window,
                                    use_lop=use_lop and cfg.use_lop,
@@ -416,6 +449,96 @@ def cross_attn_decode(cfg, lp, h, cross_cl, cross_len, *, use_lop=True,
         out = lop_decode_attention(cfg, qi, qsc, cross_cl, cross_len,
                                    window=0, use_lop=use_lop and cfg.use_lop)
     return qlinear(lp["wo"], out.reshape(b, 1, cfg.q_dim))
+
+
+# ===========================================================================
+# Latent attention (MLA) — one cached int8 row per token, absorbed form
+# ===========================================================================
+
+def _mla_latent(cfg, lp, h, positions):
+    """Project h [B, S, D] → (q_nope [B, S, H, dn], q_rope [B, S, H, dr]
+    rotated, the cache rows of the latent c ‖ k_rope by leaf name, in
+    the cache layout [B, 1, S, ...]). q and kv_a share one dispatch (one
+    barrier on h); c takes kv_a's RMSNorm, k_rope the rotary, and the
+    row is stored int8 with one absmax scale and its LOP features."""
+    dn, dc, qd = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.mla_q_dim
+    b, s, _ = h.shape
+    y = qlinear(lp["wq_kva"], h)        # zero columns pad it to 128s
+    q = y[..., :qd].reshape(b, s, cfg.n_heads, -1)
+    kva = y[..., qd:qd + cfg.latent_dim]
+    freqs = rope_freqs(cfg)
+    q_rope = rope(q[..., dn:], positions, cfg.rope_theta, freqs)
+    k_rope = rope(kva[:, :, None, dc:], positions, cfg.rope_theta, freqs)
+    c = ops.rmsnorm(kva[..., :dc], lp["kv_norm"]["g"])
+    li, lsc = _q(jnp.concatenate([c, k_rope[:, :, 0]], -1))
+    rows = {"k": li[:, None], "k_scale": lsc[:, None, :, 0],
+            "feat": pack_features(lop_features(li))[:, None]}
+    return q[..., :dn], q_rope, rows
+
+
+def _mla_query(cfg, lp, q_nope, q_rope):
+    """The absorbed query q̃_h = (q_nope_h·W_UK_hᵀ) ‖ q_rope_h, int8 per
+    head: → (qi [B, H, S, dl], qsc f32 [B, H, S]). W_UK is the k part of
+    kv_b, one BitLinear per head (heads on the expert grid axis)."""
+    b, s, h, dn = q_nope.shape
+    x = q_nope.transpose(2, 0, 1, 3).reshape(h, b * s, dn)
+    qc = qlinear_expert(lp["wuk"], x).reshape(h, b, s, -1)
+    qt = jnp.concatenate([qc.transpose(1, 0, 2, 3),
+                          q_rope.transpose(0, 2, 1, 3)], -1)
+    qi, qsc = _q(qt)
+    return qi, qsc[..., 0]
+
+
+def _mla_values(cfg, lp, o):
+    """Attention-weighted latent rows o [B, H, S, dc] → the heads' values
+    o_h·W_UV_h (one BitLinear per head), joined → [B, S, H·dv]; o_proj
+    follows outside the ``mla_attention`` mark."""
+    b, h, s, dc = o.shape
+    v = qlinear_expert(lp["wuv"], o.transpose(1, 0, 2, 3).reshape(h, b * s,
+                                                                 dc))
+    return v.reshape(h, b, s, -1).transpose(1, 2, 0, 3).reshape(b, s, -1)
+
+
+def mla_prefill_chunk(cfg, lp, h, cl, *, start, kv_len):
+    """:func:`attn_prefill_chunk` of latent attention: the chunk's latent
+    rows are written at [start, start+C) and its absorbed queries attend
+    causally over [0, kv_len) through the prefill dispatch, the latent
+    rows as keys and as values (of which the c part is kept)."""
+    c = h.shape[1]
+    q_nope, q_rope, rows = _mla_latent(cfg, lp, h,
+                                       start + jnp.arange(c)[None, :])
+    cl = _write_chunk(cl, rows, start)
+    with scope("mla_attention"):
+        qi, qsc = _mla_query(cfg, lp, q_nope, q_rope)
+        o = ops.prefill_attention(
+            qi, qsc, cl["k"], cl["k"], cl["k_scale"], cl["k_scale"], kv_len,
+            q_offset=start, causal=True,
+            softmax_scale=mla_softmax_scale(cfg))
+        v = _mla_values(cfg, lp, o[..., :cfg.kv_lora_rank])
+    return qlinear(lp["wo"], v), cl
+
+
+def mla_decode(cfg, lp, h, cl, lengths, *, use_lop, active, k_keep):
+    """:func:`attn_decode` of latent attention: append each lane's latent
+    row, then one fused decode-attention dispatch of the absorbed
+    queries — 16 heads on one latent lane, the values the rows' first
+    ``kv_lora_rank`` columns."""
+    q_nope, q_rope, rows = _mla_latent(cfg, lp, h, lengths[:, None])
+    cl = _write_token(cl, {k: v[:, :, 0] for k, v in rows.items()},
+                      lengths, active)
+    new_len = lengths + 1
+    if active is not None:
+        new_len = jnp.where(active, new_len, 0)
+    with scope("mla_attention"):
+        qi, qsc = _mla_query(cfg, lp, q_nope, q_rope)
+        out = lop_decode_attention(cfg, qi[:, :, 0], qsc[:, :, :1], cl,
+                                   new_len, window=cfg.swa_window,
+                                   use_lop=use_lop and cfg.use_lop,
+                                   k_keep=k_keep)
+        if active is not None:
+            out = jnp.where(active[:, None, None], out, 0.0)
+        v = _mla_values(cfg, lp, out[:, :, None])
+    return qlinear(lp["wo"], v), cl
 
 
 # ===========================================================================
@@ -541,9 +664,9 @@ def prefill(cfg, qp, tokens, *, frames=None, patches=None, max_len=None,
             x, out = _decoder_layer_prefill(cfg, lp, x, capacity=cap)
             return x, out["self"]
 
-        x, layers_cache = _layer_scan(body, x, qp["layers"])
-        cache = {"lengths": jnp.full((b,), true_len, jnp.int32),
-                 "layers": layers_cache}
+        cache = {"lengths": jnp.full((b,), true_len, jnp.int32)}
+        for name in _stacks(qp):
+            x, cache[name] = _layer_scan(body, x, qp[name])
     elif cfg.family == "hybrid":
         def body(x, bp):
             outs_m = []
@@ -675,9 +798,9 @@ def prefill_chunk(cfg, qp, tokens, cache, *, start, seq_end, patches=None,
                                            start=start, kv_len=kv_len)
         return _mlp(cfg, lp, x + attn_out), ncl
 
-    x, layers_cache = _layer_scan(body, x, (qp["layers"], cache["layers"]))
     new_cache = dict(cache)
-    new_cache["layers"] = layers_cache
+    for name in _stacks(qp):
+        x, new_cache[name] = _layer_scan(body, x, (qp[name], cache[name]))
     new_cache["lengths"] = jnp.full((b,), seq_end, jnp.int32)
     if all_logits:
         return _logits(cfg, qp, x), new_cache
@@ -708,9 +831,9 @@ def serve_step(cfg, qp, cache, tokens, *, use_lop=True, sp_axes=None):
                                            active=active)
             return x, ncl
 
-        x, layers_cache = _layer_scan(body, x, (qp["layers"],
-                                              cache["layers"]))
-        new_cache["layers"] = layers_cache
+        for name in _stacks(qp):
+            x, new_cache[name] = _layer_scan(body, x, (qp[name],
+                                                       cache[name]))
     elif cfg.family == "hybrid":
         def body(x, inp):
             bp, bc = inp

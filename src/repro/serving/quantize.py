@@ -25,7 +25,15 @@ weights so one kernel dispatch replaces several:
     (:func:`repro.kernels.ops.ffn_fused`), hidden state never touching
     HBM; the hidden width is zero-padded to ``FFN_HIDDEN_ALIGN``,
   * MoE expert stacks [E, k, n] fuse the same way with a leading expert
-    axis — the whole MoE layer's expert FFNs are ONE grouped dispatch.
+    axis — the whole MoE layer's expert FFNs are ONE grouped dispatch;
+    shared experts are a whole-FFN node of their own (``shared``),
+  * latent attention ``{"wq","wkv_a","kv_norm","wkv_b","wo"}`` →
+    ``{"wq_kva", "kv_norm", "wuk", "wuv", "wo"}`` (always, whatever
+    ``fuse`` says: the absorbed form needs it) — q and kv_a fuse as QKV
+    does, zero-padded to a multiple of 128 columns, and kv_b's codes
+    split per head into W_UKᵀ [H, dn, dc] and
+    W_UV [H, dc, dv], each a grouped node with kv_b's γ (heads on the
+    expert axis).
 
 ``fuse=False`` keeps the legacy one-node-per-projection format (every
 consumer still accepts it) — the dispatch-count baseline in
@@ -148,6 +156,42 @@ def _fuse_attn(node, quant: str, fuse_q: bool):
     return out
 
 
+def _fuse_mla(cfg, node):
+    """Latent-attention dict → the absorbed-form serving dict."""
+    h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    wkb = node["wkv_b"]["w"]
+    lead, dc = wkb.shape[:-2], wkb.shape[-2]
+
+    def split(w):
+        wt, gamma = ternary_quantize(w)                 # kv_b's one γ
+        wt = wt.reshape(dc, h, dn + dv).transpose(1, 0, 2)
+        uk = jax.vmap(pack_ternary)(wt[..., :dn].transpose(0, 2, 1))
+        uv = jax.vmap(pack_ternary)(wt[..., dn:])
+        g = jnp.broadcast_to(gamma.reshape(1, 1, 1), (h, 1, 1))
+        return uk, uv, g
+
+    uk, uv, g = jax.vmap(split)(wkb.reshape((-1,) + wkb.shape[-2:]))
+    q_kva = _concat_packed([_quantize_linear(node["wq"]["w"]),
+                            _quantize_linear(node["wkv_a"]["w"])])
+    # zero codes (and γ) pad q ‖ kv_a to the 128-lane grid: a whole-dim
+    # block of DeepSeek-V2's 3648 columns overflows the kernel's VMEM
+    n = q_kva["packed"].shape[-1]
+    n_pad = -(-n // FFN_HIDDEN_ALIGN) * FFN_HIDDEN_ALIGN
+    q_kva = _pad_codes(q_kva, n_pad, axis=-1)
+    q_kva["scale"] = jnp.pad(q_kva["scale"], [(0, 0)] * (
+        q_kva["scale"].ndim - 1) + [(0, n_pad - n)])
+    out = {"wq_kva": q_kva,
+           "kv_norm": dict(node["kv_norm"]),
+           "wuk": {"packed": uk.reshape(lead + uk.shape[1:]),
+                   "scale": g.reshape(lead + g.shape[1:])},
+           "wuv": {"packed": uv.reshape(lead + uv.shape[1:]),
+                   "scale": g.reshape(lead + g.shape[1:])},
+           "wo": _quantize_node("wo", node["wo"], cfg.quant)}
+    _check_packed(out["wuk"], dn)
+    _check_packed(out["wuv"], dc)
+    return out
+
+
 def _fuse_ffn(node, quant: str):
     """FFN dict (dense {"w_*": {"w"}} or MoE raw [E, k, n] stacks + router)
     → whole-FFN serving node, or None when ineligible."""
@@ -191,6 +235,8 @@ def quantize_params(cfg, params, *, fuse: bool = True):
     def walk(path, node):
         if isinstance(node, dict):
             name = path[-1] if path else ""
+            if name == "attn" and "wkv_b" in node:
+                return _fuse_mla(cfg, node)
             if fuse and name in ("attn", "xattn"):
                 fused = _fuse_attn(node, cfg.quant, fuse_q=name == "attn")
                 if fused is not None:
@@ -204,7 +250,10 @@ def quantize_params(cfg, params, *, fuse: bool = True):
             if fuse and "w_up" in node and "w_down" in node:
                 fused = _fuse_ffn(node, cfg.quant)
                 if fused is not None:
-                    return fused
+                    # what rides beside the experts (shared experts)
+                    return {key: walk(path + (key,), val)
+                            if isinstance(val, dict) else val
+                            for key, val in fused.items()}
             if "w" in node and not isinstance(node["w"], dict):
                 return _quantize_node(path[-1] if path else "", node,
                                       cfg.quant)

@@ -50,8 +50,9 @@ with use_mesh(mesh):
         use_lop=True, sp_axes=("model",)))(qi, qsc, cl, lengths)
 
 # local reference: write + dense attention (keep=1 → LOP is exact)
-from repro.serving.engine import _write_token
-cl_local = _write_token(dict(cl), ki, vi, ksc, vsc, feat_new, lengths)
+from repro.serving.engine import _kv_rows, _write_token
+cl_local = _write_token(dict(cl), _kv_rows(ki, vi, ksc[..., 0], vsc[..., 0],
+                                           feat_new), lengths)
 out_local = lop_decode_attention(cfg, qi, qsc, cl_local, lengths + 1,
                                  window=0, use_lop=False)
 

@@ -7,7 +7,9 @@ These tests hand the kernels to Mosaic for a described, unattached v5e
 chip — nothing runs, so they check compilation only — at the widths
 ``chip_smoke.py`` serves: d 3200, 32 heads of dim 100, FFN hidden 8640
 (padded to 8704), 2176- and 4096-token caches in 128-token LOP blocks;
-and the LOP decode at stablelm-1.6b's head dim 64.
+the LOP decode at stablelm-1.6b's head dim 64; and DeepSeek-V2-Lite's
+latent attention (16 heads on one latent lane of 576, values its first
+512 columns) and its 64 experts of width 1408.
 
 The topology is described inside a module fixture, never at import:
 every test worker imports this file, and loading the TPU library is
@@ -168,5 +170,66 @@ def test_prefill_attention_compiles(compile_for):
         ((1, H, c, DH), jnp.int8), ((1, H, c), jnp.float32),
         ((1, hkv, M, DH), jnp.int8), ((1, hkv, M, DH), jnp.int8),
         ((1, hkv, M), jnp.float32), ((1, hkv, M), jnp.float32),
+        ((1,), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+# DeepSeek-V2-Lite: latent attention and experts at published widths
+DS = get_config("deepseek-v2-lite")
+DL, DC = DS.latent_dim, DS.kv_lora_rank             # 576, 512
+DS_M = round_up(2048 + 1, DS.lop_block)
+
+
+@pytest.mark.parametrize("use_lop", [True, False], ids=["lop", "dense"])
+def test_mla_decode_attention_compiles(compile_for, use_lop):
+    """16 query heads on one latent lane at d 576, the values the rows'
+    first 512 columns (no V operand), 8 lanes of a 2176-token pool; the
+    launches carry the ``mla_attention`` mark they are traced in."""
+    from repro.core.marks import scope
+    from repro.models.layers import mla_softmax_scale
+    b, h = 8, DS.n_heads
+
+    def fn(qi, qs, k, ks, f, nl):
+        with scope("mla_attention"):
+            return ops.decode_attention(
+                qi, qs, k, None, ks, None, f, nl, block=DS.lop_block,
+                k_keep=k_keep_blocks(DS, DS_M), use_lop=use_lop,
+                softmax_scale=mla_softmax_scale(DS), v_cols=DC,
+                impl="pallas")
+
+    hlo = compile_for(fn, ((b, h, DL), jnp.int8), ((b, h, 1), jnp.float32),
+                      ((b, 1, DS_M, DL), jnp.int8),
+                      ((b, 1, DS_M), jnp.float32),
+                      ((b, 1, DS_M, DL // 2), jnp.uint8), ((b,), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (
+        2 if use_lop else 1)
+    if use_lop:
+        assert '"within":"mla_attention"' in hlo
+
+
+@pytest.mark.parametrize("m", [8, 128])
+def test_expert_ffn_compiles(compile_for, m):
+    """All 64 routed experts (2048 → 1408 → 2048) in one grouped launch,
+    each bucket of a decode step's 8 rows or a prefill chunk's 128."""
+    e, d, f = DS.n_experts, DS.d_model, DS.d_expert
+    hlo = compile_for(
+        lambda x, gu, gs, dp, ds: ops.ffn_fused(
+            x, gu, gs, dp, ds, gated=True, act="silu", impl="pallas"),
+        ((e, m, d), jnp.float32), ((e, d // 4, 2 * f), jnp.uint8),
+        ((e, 1, 2 * f), jnp.float32), ((e, f // 4, d), jnp.uint8),
+        ((e, 1, 1), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_mla_prefill_attention_compiles(compile_for):
+    """A 128-token chunk's 16 absorbed query heads (2048 rows of 576)
+    over the latent rows as keys and values: the rows tile into slabs
+    that fit the kernel's VMEM."""
+    c, h = DS.lop_block, DS.n_heads
+    hlo = compile_for(
+        lambda qi, qs, k, ks, nl, qo: ops.prefill_attention(
+            qi, qs, k, k, ks, ks, nl, q_offset=qo, impl="pallas"),
+        ((1, h, c, DL), jnp.int8), ((1, h, c), jnp.float32),
+        ((1, 1, DS_M, DL), jnp.int8), ((1, 1, DS_M), jnp.float32),
         ((1,), jnp.int32), ((), jnp.int32))
     assert "tpu_custom_call" in hlo

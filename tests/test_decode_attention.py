@@ -56,6 +56,25 @@ def test_fused_dense_matches_ref(h, hkv, window):
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=1e-4)
 
 
+@pytest.mark.parametrize("use_lop", [True, False], ids=["lop", "dense"])
+def test_latent_values_from_key_rows_match_ref(use_lop):
+    """Latent attention: 8 query heads on one latent lane, the values the
+    key rows' first 32 columns with the key scales (no V operand), with
+    the screen pruning to 3 of 8 blocks, against the oracle given that
+    slice as its V."""
+    b, h, m, dl, dc, block = 2, 8, 256, 40, 32, 32
+    qi, qs, k, _, ks, _, feat = _setup(b, h, 1, m, dl)
+    new_len = jnp.asarray([201, 0], jnp.int32)      # a retired lane too
+    kw = dict(block=block, k_keep=3, use_lop=use_lop, softmax_scale=0.11)
+    o_k = ops.decode_attention(qi, qs, k, None, ks, None, feat, new_len,
+                               v_cols=dc, impl="pallas", **kw)
+    o_r = ops.decode_attention(qi, qs, k, k[..., :dc], ks, ks, feat,
+                               new_len, impl="ref", **kw)
+    assert o_k.shape == (b, h, dc)
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=1e-4)
+    assert not np.asarray(o_k[1]).any()
+
+
 def test_lop_at_full_keep_equals_dense():
     """K = NB candidates → the sparse pipeline is exact (paper's K=M)."""
     b, h, hkv, m, dh, block = 2, 8, 2, 256, 32, 32

@@ -140,24 +140,32 @@ def test_capacity_guard_rejects_oversized_request():
 
 
 def test_moe_exact_length_prefill_matches_lockstep():
-    """MoE routers rank tokens per group for expert capacity, so pad
-    tokens entering the router shift who gets dropped — ``_bucket`` must
-    use exact lengths for moe like the recurrent families (ROADMAP open
-    item from the PR 2 review). 17 would land in the pow2 bucket 32 and
-    pad; with the fix it compiles at exactly 17 and the pooled run stays
-    token-identical to the unpadded lockstep reference."""
+    """MoE serves dropless experts (every expert's bucket holds every row
+    of the call), so a token's output depends on its own row alone: moe
+    prefills in fixed-size chunks like dense — no exact-length or pow2
+    compiles — interns prefix pages, and a prefix hit still decodes
+    token-identically to the unpadded whole-prompt lockstep reference.
+    17 fits one 32-token chunk, 40 takes two, and the last prompt shares
+    its first 32-token block with the 40-token one."""
     cfg, qp = _setup("granite-moe-1b-a400m")
-    prompts = _prompts(cfg, [17, 23], seed=11)
+    base = _prompts(cfg, [17, 23, 40], seed=11)
+    prompts = base + [np.concatenate([base[2][:32], _prompts(
+        cfg, [9], seed=12)[0]])]
     sched = Scheduler(cfg, qp, n_slots=2, max_len=MAX_LEN)
-    assert not sched.chunked          # router caveat: run-to-completion
-    for rid, p in enumerate(prompts):
-        sched.submit(Request(rid=rid, prompt=p, max_new_tokens=5))
-    results = sched.run_to_completion()
-    assert sched.prefill_compiles == 2           # exact lengths, no buckets
+    assert sched.chunked and sched.prefix_store is not None
+    results = []
+    for batch in (range(3), [3]):                # the hit comes after
+        for rid in batch:
+            sched.submit(Request(rid=rid, prompt=prompts[rid],
+                                 max_new_tokens=5))
+        results += sched.run_to_completion()
+    assert sched.prefill_compiles == 1           # one chunk shape
+    assert sched.prefix_store.hits >= 1
     for rid, p in enumerate(prompts):
         got = next(r for r in results if r.rid == rid)
         ref = lockstep_generate(cfg, qp, p, 5, max_len=MAX_LEN)
         assert got.tokens == ref, (rid, got.tokens, ref)
+    assert next(r for r in results if r.rid == 3).cached_len == 32
 
 
 def test_recurrent_family_uses_exact_length_prefill():

@@ -255,7 +255,10 @@ def test_pooled_engine_satisfies_protocol(setup):
     assert eng.state_kind == "paged-kv" and not eng.has_image_prefix
     moe = _reduced("granite-moe-1b-a400m")
     eng_moe = PooledEngine(moe, qp, max_len=MAX_LEN)
-    assert eng_moe.exact_length_prefill and not eng_moe.supports_chunked
+    # dropless experts: moe chunks and caches prefixes like dense
+    assert eng_moe.supports_chunked and not eng_moe.exact_length_prefill
+    assert eng_moe.prefix_block == moe.lop_block
+    assert not eng_moe.supports_speculative
 
 
 def test_scheduler_has_no_family_name_checks():
